@@ -1114,7 +1114,7 @@ def _capacity_figure(n_pods: int = 32) -> dict:
     micro-tick daemon — an in-process cluster loaded to a meaningful
     fill so the fragmentation score and probe-shape headroom read
     back non-trivially (the acceptance gate pins both keys in this
-    artifact; tools/update_readme_bench.py renders them)."""
+    artifact)."""
     from kubernetes_tpu.client import Client, LocalTransport
     from kubernetes_tpu.scheduler.daemon import (
         IncrementalBatchScheduler,
@@ -2061,12 +2061,12 @@ def main() -> None:
 
 
 if __name__ == "__main__":
-    try:
-        from kubernetes_tpu import native as _native
+    from kubernetes_tpu import native as _native
+    from kubernetes_tpu.utils.compile_cache import place_compile_cache
 
-        _native.ensure_built()  # best-effort; NumPy fallback otherwise
-    except Exception:
-        pass
+    place_compile_cache()
+    if not _native.ensure_built(quiet=False):
+        sys.exit("bench: native/ failed to build (make -C native lib)")
     mode = os.environ.get("BENCH_MODE", "backlog")
     if mode == "churn":
         churn_main()

@@ -34,14 +34,32 @@ LABEL_HOST = "tpu.kubernetes-tpu.io/host-index"
 LABEL_COORDS = "tpu.kubernetes-tpu.io/coords"
 
 
+def _local_tpu_devices() -> list:
+    """This host's TPU devices. Opening them loads libtpu, which one
+    process per host may hold (JAX offers no device list without
+    loading it): with a --batch scheduler on this host, it is that
+    scheduler's. Refuse with the reason rather than hang or describe
+    the CPU backend."""
+    import jax
+
+    try:
+        return jax.devices("tpu")
+    except RuntimeError as e:
+        raise SystemExit(
+            "--cloud-provider tpu: cannot open this host's TPU, which "
+            "one process at a time may hold — a --batch scheduler on "
+            "this host has it, or the host has none. Run the "
+            "controller-manager with --cloud-provider tpu on a host "
+            f"whose chips are free, or without it. ({e})"
+        ) from e
+
+
 class TPUCloudProvider(CloudProvider):
     name = "tpu"
 
     def __init__(self, devices=None, slice_name: str = "slice-0"):
         if devices is None:
-            import jax
-
-            devices = jax.devices()
+            devices = _local_tpu_devices()
         self.devices = list(devices)
         self.slice_name = slice_name
         # Managed routes (RouteController's pod-CIDR routes) layered on

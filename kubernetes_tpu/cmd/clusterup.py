@@ -16,8 +16,12 @@ an inventory-driven planner with two providers:
 The plan a single inventory produces:
   master host:  apiserver (--data-dir for durability) and, per
                 control_plane_replicas, controller-manager + scheduler
-                pairs with --leader-elect (hot standbys; the batch
-                scheduler when the inventory says so)
+                pairs with --leader-elect (hot standbys)
+  batch:        with "batch_scheduler", the schedulers run --batch and
+                each holds its host's chips, which one process at a
+                time may open: one --batch scheduler per host. The
+                local provider starts one; the ssh provider starts one
+                on each host of "scheduler_hosts" (default: the master)
   node hosts:   one kubelet each (process or fake runtime) + optional
                 kube-proxy
   addons:       python -m kubernetes_tpu.addons (--dns/--monitoring)
@@ -26,6 +30,7 @@ Inventory (JSON):
   {"master": {"host": "10.0.0.1", "port": 8080, "data_dir": "/var/..."},
    "control_plane_replicas": 2,
    "batch_scheduler": true,
+   "scheduler_hosts": ["10.0.0.1", "10.0.0.5"],
    "nodes": [{"name": "node-0", "host": "10.0.0.2"}, ...],
    "runtime": "fake" | "process",
    "addons": ["dns", "monitoring"]}
@@ -72,7 +77,16 @@ def load_inventory(path: str) -> dict:
     return inv
 
 
-def plan(inv: dict) -> List[Tuple[str, str, List[str]]]:
+def batch_scheduler_hosts(inv: dict, provider: str = "local") -> List[str]:
+    """Hosts that run a --batch scheduler: one each, since a process
+    that opens a host's accelerator holds it alone."""
+    master = inv["master"]["host"]
+    if provider == "local":
+        return [master]
+    return list(dict.fromkeys(inv.get("scheduler_hosts") or [master]))
+
+
+def plan(inv: dict, provider: str = "local") -> List[Tuple[str, str, List[str]]]:
     """-> [(host, role, argv)] in start order."""
     m = inv["master"]
     server = f"http://{m['host']}:{m['port']}"
@@ -85,7 +99,8 @@ def plan(inv: dict) -> List[Tuple[str, str, List[str]]]:
     if m.get("data_dir"):
         apiserver += ["--data-dir", m["data_dir"]]
     out.append((m["host"], "apiserver", apiserver))
-    for i in range(int(inv["control_plane_replicas"])):
+    replicas = int(inv["control_plane_replicas"])
+    for i in range(replicas):
         out.append(
             (m["host"], f"controller-manager-{i}", [
                 sys.executable, HYPERKUBE, "controller-manager",
@@ -93,13 +108,17 @@ def plan(inv: dict) -> List[Tuple[str, str, List[str]]]:
                 "--healthz-port", "-1",
             ])
         )
-        sched = [
-            sys.executable, HYPERKUBE, "scheduler",
-            "--server", server, "--leader-elect", "--healthz-port", "-1",
-        ]
-        if inv.get("batch_scheduler"):
-            sched.append("--batch")
-        out.append((m["host"], f"scheduler-{i}", sched))
+    sched = [
+        sys.executable, HYPERKUBE, "scheduler",
+        "--server", server, "--leader-elect", "--healthz-port", "-1",
+    ]
+    if inv.get("batch_scheduler"):
+        sched_hosts = batch_scheduler_hosts(inv, provider)
+        sched.append("--batch")
+    else:
+        sched_hosts = [m["host"]] * replicas
+    for i, host in enumerate(sched_hosts):
+        out.append((host, f"scheduler-{i}", sched))
     for node in inv["nodes"]:
         kubelet = [
             sys.executable, HYPERKUBE, "kubelet",
@@ -142,7 +161,15 @@ def _wait_healthy(server: str, timeout: float = 30.0) -> bool:
 
 def up(inv: dict, state_dir: str, provider: str = "local",
        dry_run: bool = False) -> int:
-    steps = plan(inv)
+    steps = plan(inv, provider)
+    n_batch = len(batch_scheduler_hosts(inv, provider))
+    if inv.get("batch_scheduler") and n_batch < int(inv["control_plane_replicas"]):
+        print(
+            f"note: {n_batch} --batch scheduler(s), not "
+            f"{inv['control_plane_replicas']}: HA scheduler pairs need one "
+            "chip per replica, so they come from --provider ssh with one "
+            "host per replica in the inventory's scheduler_hosts"
+        )
     if dry_run:
         for host, role, argv in steps:
             print(f"[{host}] {role}: {' '.join(argv)}")

@@ -33,6 +33,9 @@ def main(argv: Optional[List[str]] = None) -> int:
         print(f"usage: hyperkube <server> [flags]\nservers: {', '.join(names)}")
         return 0 if argv else 1
     name, rest = argv[0], argv[1:]
+    from kubernetes_tpu.utils.compile_cache import place_compile_cache
+
+    place_compile_cache()
     if name == "ktctl":
         from kubernetes_tpu.cli.ktctl import main as ktctl_main
 

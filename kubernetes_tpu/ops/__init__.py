@@ -7,14 +7,10 @@ against ALL nodes as vector ops. Node-axis arrays shard over a
 jax.sharding.Mesh for multi-chip scale-out.
 """
 
-# NOTE on the persistent XLA compilation cache: deliberately NOT
-# enabled here. Measured on this image, the cache never captured the
-# big solver executables (only trivial jit_broadcast-type entries), and
-# loading its AOT artifacts on a different host than compiled them
-# trips XLA's machine-feature mismatch path (cpu_aot_loader: "could
-# lead to SIGILL"). Shape-bucketing (matrices._pod_axis_bucket) is the
-# mechanism that actually bounds recompiles. Operators who want the
-# cache can set JAX_COMPILATION_CACHE_DIR themselves.
+# The persistent compilation cache is placed by the entry points, never
+# at import (utils/compile_cache.py). Shape-bucketing
+# (matrices._pod_axis_bucket) bounds how many executables a daemon
+# compiles; the cache lets a restarted process load them instead.
 
 from kubernetes_tpu.ops.matrices import DeviceSnapshot, device_snapshot
 from kubernetes_tpu.ops.pipeline import solve_backlog_pipelined

@@ -914,15 +914,17 @@ def sharded_abstract_args(
     return args, kwargs
 
 
-#: One partitioned-HLO collective op: result dtype, result dims, kind.
-#: Matched per line so the all-gather `dimensions={d}` attribute (the
-#: gathered dim — what the pod-axis full-gather check needs) can be
-#: read off the same line.
+#: One partitioned-HLO collective op: its result type (one array, or
+#: a tuple of arrays when XLA's combiner fused several collectives of
+#: one kind into a single op) and its kind. Matched per line so the
+#: all-gather `dimensions={d}` attribute (the gathered dim — what the
+#: pod-axis full-gather check needs) can be read off the same line.
 _COLLECTIVE_LINE_RE = re.compile(
-    r"= (?P<dtype>[a-z]+\d*)\[(?P<dims>[\d,]*)\][^ ]* "
+    r"= (?P<result>\([^()]*\)|[a-z]+\d*\[[\d,]*\]\S*) "
     r"(?P<kind>all-gather|all-reduce|reduce-scatter|collective-permute"
     r"|all-to-all)\("
 )
+_ARRAY_TYPE_RE = re.compile(r"(?P<dtype>[a-z]+\d*)\[(?P<dims>[\d,]*)\]")
 _GATHER_DIM_RE = re.compile(r"dimensions=\{(\d+)\}")
 
 _HLO_DTYPE_BYTES = {
@@ -949,28 +951,27 @@ def collective_inventory(hlo_text: str) -> Dict[str, object]:
         if m is None:
             continue
         kind = m.group("kind")
-        dims = (
-            tuple(int(d) for d in m.group("dims").split(","))
-            if m.group("dims")
-            else ()
-        )
-        width = _HLO_DTYPE_BYTES.get(m.group("dtype"), 4)
-        n_elem = 1
-        for d in dims:
-            n_elem *= d
-        counts[kind] = counts.get(kind, 0) + 1
-        byte_volume[kind] = byte_volume.get(kind, 0) + n_elem * width
-        op: Dict[str, object] = {
-            "kind": kind,
-            "dtype": m.group("dtype"),
-            "shape": list(dims),
-            "bytes": n_elem * width,
-        }
-        if kind in ("all-gather", "all-to-all"):
-            gm = _GATHER_DIM_RE.search(line)
-            if gm is not None:
+        gm = _GATHER_DIM_RE.search(line)
+        # A combined (tuple-result) collective counts once per element:
+        # the inventory names the logical collectives the partitioner
+        # inserted, whether or not the combiner fused them into one op.
+        for t in _ARRAY_TYPE_RE.finditer(m.group("result")):
+            dims = tuple(int(d) for d in t.group("dims").split(",") if d)
+            width = _HLO_DTYPE_BYTES.get(t.group("dtype"), 4)
+            n_elem = 1
+            for d in dims:
+                n_elem *= d
+            counts[kind] = counts.get(kind, 0) + 1
+            byte_volume[kind] = byte_volume.get(kind, 0) + n_elem * width
+            op: Dict[str, object] = {
+                "kind": kind,
+                "dtype": t.group("dtype"),
+                "shape": list(dims),
+                "bytes": n_elem * width,
+            }
+            if kind in ("all-gather", "all-to-all") and gm is not None:
                 op["gather_dim"] = int(gm.group(1))
-        ops.append(op)
+            ops.append(op)
     return {
         "counts": counts,
         "bytes": byte_volume,

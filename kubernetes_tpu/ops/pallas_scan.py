@@ -34,6 +34,7 @@ plugin/pkg/scheduler/scheduler.go:113-158 + generic_scheduler.go.
 from __future__ import annotations
 
 import functools
+import logging
 import os
 from typing import Dict, Tuple
 
@@ -43,6 +44,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 
 from kubernetes_tpu.ops.ledger import traced_jit
+
+_LOG = logging.getLogger("kubernetes_tpu.pallas")
 
 # Lane layout of the packed per-pod row (i32). Bitset word counts are
 # static per compiled kernel (shape-derived).
@@ -72,37 +75,55 @@ def _vmem_bytes(N: int, S: int, LW: int, PW: int, VW: int) -> int:
     return counts + words + rows
 
 
+#: Largest node axis the kernel takes: the packed (score, 8191-idx)
+#: select needs N <= 8192.
+MAX_NODES = 8192
+
+
+@functools.lru_cache(maxsize=None)
+def _log_ineligible(reason: str) -> None:
+    """Once per distinct reason: a TPU solve the kernel cannot take runs
+    the slower XLA scan, and that must show in the log."""
+    _LOG.warning("pallas scan not eligible (%s): running the XLA scan", reason)
+
+
 def pallas_eligible(pods: Dict, nodes: Dict, lspec) -> bool:
-    """Default spec, single unsharded TPU device, VMEM-sized shapes."""
+    """Default spec, single unsharded TPU device, VMEM-sized shapes —
+    decided from explicit checks on the node carry's placement and
+    shape. A single-TPU default-spec solve turned away for its size is
+    logged (`_log_ineligible`)."""
     if os.environ.get("KTPU_PALLAS", "") == "off":
         return False
     from kubernetes_tpu.models.algspec import DEFAULT_LOWERED
 
     if lspec != DEFAULT_LOWERED:
         return False  # policy columns: XLA scan carries them
-    try:
-        arr = nodes["cpu_cap"]
-        if len(getattr(arr, "devices", lambda: [None])()) != 1:
-            return False
-        platform = next(iter(arr.devices())).platform
-    except Exception:
-        return False
-    if platform != "tpu":
-        return False
-    N = nodes["cpu_cap"].shape[0]
+    arr = nodes["cpu_cap"]
+    if not isinstance(arr, jax.Array):
+        return False  # host arrays: jit places them on the default device
+    if isinstance(arr, jax.core.Tracer):
+        return False  # traced into an enclosing jit: placement unknown
+    devices = arr.sharding.device_set
+    if len(devices) != 1 or next(iter(devices)).platform != "tpu":
+        return False  # a mesh, or the CPU backend
+    N = arr.shape[0]
     S = nodes["svc_counts"].shape[1]
-    if N > 8192:
-        return False  # the packed (score, 8191-idx) select needs N <= 8192
-    return (
-        _vmem_bytes(
-            N,
-            S,
-            nodes["labels"].shape[1],
-            nodes["uport"].shape[1],
-            nodes["uvol_any"].shape[1],
-        )
-        <= VMEM_BUDGET_BYTES
+    if N > MAX_NODES:
+        _log_ineligible(f"N={N} > {MAX_NODES} nodes")
+        return False
+    vmem = _vmem_bytes(
+        N,
+        S,
+        nodes["labels"].shape[1],
+        nodes["uport"].shape[1],
+        nodes["uvol_any"].shape[1],
     )
+    if vmem > VMEM_BUDGET_BYTES:
+        _log_ineligible(
+            f"N={N} S={S} needs {vmem} B of VMEM > {VMEM_BUDGET_BYTES}"
+        )
+        return False
+    return True
 
 
 def _pack_pods(pods: Dict) -> jnp.ndarray:

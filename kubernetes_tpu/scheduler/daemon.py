@@ -801,6 +801,12 @@ class BatchScheduler(Scheduler):
             else:
                 decisions = preempt_backlog_tpu(candidates, nodes, assigned)
         except Exception:
+            _LOG.exception(
+                "preemption victim selection failed on the %s path; "
+                "falling back to the scalar path",
+                "scalar" if self.policy_scalar or self.sidecar is not None
+                else "device (preemption kernel)",
+            )
             self.fallback_count += 1
             try:
                 decisions = preempt_backlog_scalar(candidates, nodes, assigned)
@@ -1300,6 +1306,14 @@ class BatchScheduler(Scheduler):
             # Device path unavailable: scalar fallback with the
             # CONFIGURED plugin set — and the HOST acceptance reducer
             # (the device reducer would just re-raise the same outage).
+            _LOG.exception(
+                "batch solve of %d pods failed (%s, mode=%s); falling "
+                "back to the scalar path",
+                len(pending),
+                "sidecar" if self.sidecar is not None
+                else "scalar policy" if self.policy_scalar else "device",
+                self.mode,
+            )
             self.fallback_count += 1
             try:
                 destinations, denied = run(
@@ -1309,6 +1323,10 @@ class BatchScheduler(Scheduler):
                     None,
                 )
             except Exception:
+                _LOG.exception(
+                    "scalar fallback solve failed too; requeueing %d pods",
+                    len(pending),
+                )
                 self._requeue_many(pending)
                 return len(pending)
             solve_s = time.monotonic() - t0
@@ -1666,6 +1684,11 @@ class IncrementalBatchScheduler(BatchScheduler):
             # Device/readback failure mid-pipeline: invalidate the
             # session and send the tick's pods back through the queue
             # (the next tick rebuilds and re-solves them).
+            _LOG.exception(
+                "session solve readback failed (mode=%s, %d pods); "
+                "invalidating the session and requeueing",
+                self.mode, len(ctx["pending"]),
+            )
             self._session = None
             self.fallback_count += 1
             for pod in ctx["pending"]:
@@ -2015,7 +2038,10 @@ class IncrementalBatchScheduler(BatchScheduler):
                 try:
                     self._session = self._build_session()
                 except Exception:
-                    _LOG.debug("eager session build failed", exc_info=True)
+                    _LOG.exception(
+                        "eager session build (upload + pre-warm "
+                        "compiles) failed"
+                    )
                     self._session = None
             else:
                 # No session to apply them to, and the next build
@@ -2179,6 +2205,19 @@ class IncrementalBatchScheduler(BatchScheduler):
             # in-flight solve MUST commit (and the worker drain) first
             # — the fallback snapshots the pod lister, and uncommitted
             # binds would let it double-book their capacity.
+            from kubernetes_tpu.ops import RebuildRequired
+
+            if isinstance(e, RebuildRequired):
+                _LOG.warning(
+                    "session capacity exhausted (%s); this tick runs "
+                    "the full-relower path and the next rebuilds", e,
+                )
+            elif not isinstance(e, _SessionInvalidated):
+                _LOG.exception(
+                    "session tick of %d pods failed (mode=%s); falling "
+                    "back to the full-relower path",
+                    len(pending), self.mode,
+                )
             try:
                 self._resolve_inflight()
                 self._flush_commits()

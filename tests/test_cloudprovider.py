@@ -30,7 +30,11 @@ from kubernetes_tpu.server.api import APIServer
 class TestRegistry:
     def test_builtin_providers_registered(self):
         assert isinstance(get_provider("fake"), FakeCloudProvider)
-        assert isinstance(get_provider("tpu"), TPUCloudProvider)
+        # The TPU provider opens this host's TPU; on a host where it
+        # cannot (none here, or one held by a --batch scheduler) it
+        # refuses by name instead of describing the CPU backend.
+        with pytest.raises(SystemExit, match="cloud-provider tpu"):
+            get_provider("tpu")
 
     def test_unknown_provider(self):
         with pytest.raises(KeyError):
@@ -44,7 +48,7 @@ class TestRegistry:
 class TestTPUProvider:
     def test_discovers_hosts_from_devices(self):
         # conftest forces 8 virtual CPU devices in one process = 1 host.
-        provider = TPUCloudProvider()
+        provider = TPUCloudProvider(devices=jax.devices())
         instances = provider.instances()
         assert len(instances) == 1
         inst = instances[0]
@@ -55,7 +59,9 @@ class TestTPUProvider:
         assert LABEL_PLATFORM in labels and LABEL_CHIP in labels
 
     def test_zone_is_slice_scoped(self):
-        provider = TPUCloudProvider(slice_name="slice-A")
+        provider = TPUCloudProvider(
+            devices=jax.devices(), slice_name="slice-A"
+        )
         zone = provider.zone_of("tpu-host-0")
         assert zone == Zone(failure_domain="slice-A/host-0", region="slice-A")
         assert provider.zone_of("nope") is None
@@ -123,7 +129,9 @@ class TestCloudNodeController:
         assert names == {"manual-node"}  # cloud node gone, manual kept
 
     def test_tpu_provider_end_to_end(self):
-        ctl = CloudNodeController(self.client, TPUCloudProvider())
+        ctl = CloudNodeController(
+            self.client, TPUCloudProvider(devices=jax.devices())
+        )
         assert ctl.sync_once() == 1
         node = self.client.get("nodes", "tpu-host-0")
         assert node.metadata.labels[LABEL_MANAGED] == "cloud"

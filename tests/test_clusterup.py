@@ -62,6 +62,31 @@ class TestPlan:
         api = next(a for _h, r, a in steps if r == "apiserver")
         assert "--data-dir" in api
 
+    @pytest.mark.parametrize(
+        "provider,scheduler_hosts,expect",
+        [
+            ("local", None, ["127.0.0.1"]),
+            ("local", ["h1", "h2"], ["127.0.0.1"]),
+            ("ssh", None, ["127.0.0.1"]),
+            ("ssh", ["h1", "h2", "h1"], ["h1", "h2"]),
+        ],
+    )
+    def test_one_batch_scheduler_per_host(
+        self, tmp_path, provider, scheduler_hosts, expect
+    ):
+        """A --batch scheduler holds its host's chips, which one process
+        at a time may open: never two on one host."""
+        inv = load_inventory(inventory(tmp_path, 18125, replicas=2))
+        inv["batch_scheduler"] = True
+        if scheduler_hosts:
+            inv["scheduler_hosts"] = scheduler_hosts
+        scheds = [
+            (h, a) for h, r, a in plan(inv, provider)
+            if r.startswith("scheduler")
+        ]
+        assert [h for h, _a in scheds] == expect
+        assert all("--batch" in a for _h, a in scheds)
+
     def test_ssh_provider_dry_run(self, tmp_path, capsys):
         """--dry-run prints the full per-host plan and starts nothing."""
         inv_path = inventory(tmp_path, 18124)

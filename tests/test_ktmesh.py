@@ -229,6 +229,17 @@ class TestCollectiveInventory:
         assert ag["gather_dim"] == 1
         assert ag["shape"] == [384, 8]
 
+    def test_combined_tuple_collective_counts_each_element(self):
+        # XLA's combiner (JAX 0.9) fuses independent all-reduces into
+        # one tuple-result op; the inventory still names each of them.
+        inv = C.collective_inventory(
+            "%all-reduce.6 = (s32[32]{0}, s32[32]{0}, s32[], s32[], "
+            "s32[], /*index=5*/s32[]) all-reduce(%a, %b, %c, %d, %e, "
+            "%f), channel_id=1, to_apply=%add"
+        )
+        assert inv["counts"] == {"all-reduce": 6}
+        assert inv["bytes"] == {"all-reduce": 2 * 128 + 4 * 4}
+
     def test_collective_free_module(self):
         inv = C.collective_inventory("%x = f32[8]{0} add(%a, %b)")
         assert inv == {"counts": {}, "bytes": {}, "total": 0, "ops": []}

@@ -108,6 +108,17 @@ class TestDispatch:
         # engage; solver.solve falls back to the XLA scan.
         assert not pallas_eligible(d.pods, d.nodes, DEFAULT_LOWERED)
 
+    def test_host_arrays_not_eligible(self):
+        # NumPy columns carry no device placement: ineligible by the
+        # explicit type check, not by a swallowed exception.
+        pods, nodes, assigned, services = random_cluster(0)
+        snap = build_snapshot(
+            pods, nodes, assigned_pods=assigned, services=services
+        )
+        d = device_snapshot(snap)
+        host = {k: np.asarray(v) for k, v in d.nodes.items()}
+        assert not pallas_eligible(d.pods, host, DEFAULT_LOWERED)
+
     def test_env_kill_switch(self, monkeypatch):
         monkeypatch.setenv("KTPU_PALLAS", "off")
         pods, nodes, assigned, services = random_cluster(0)

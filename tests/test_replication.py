@@ -306,13 +306,29 @@ class TestHTTPPlane:
 
         _store, _api, leader_http, hub, followers = self._cluster()
         f1_http = followers[0][2]
+
+        def logged(match, n):
+            # Each hop logs in its handler's finally, AFTER its response
+            # is sent: the follower's entry can land after the client
+            # has already read the reply.
+            deadline = time.monotonic() + 5.0
+            while True:
+                got = [
+                    e for e in list(debug.DEFAULT_REQUEST_LOG._ring)
+                    if match(e)
+                ]
+                if len(got) >= n or time.monotonic() > deadline:
+                    return got
+                time.sleep(0.01)
+
+        def is_post(e):
+            return e[1] == "POST" and e[2].endswith("/pods")
+
         try:
             c = Client(HTTPTransport(f1_http.address))
+            earlier = len(logged(is_post, 0))
             c.create("pods", pod_wire("traced"))
-            posts = [
-                e for e in list(debug.DEFAULT_REQUEST_LOG._ring)
-                if e[1] == "POST" and e[2].endswith("/pods")
-            ]
+            posts = logged(is_post, earlier + 2)
             # The leader's hop logs first (it responds before the
             # follower's own finally runs), then the follower's.
             assert len(posts) >= 2
@@ -330,10 +346,7 @@ class TestHTTPPlane:
                 method="POST",
             )
             urllib.request.urlopen(req, timeout=10).read()
-            stamped = [
-                e for e in list(debug.DEFAULT_REQUEST_LOG._ring)
-                if e[5] == "trace-fwd-regress"
-            ]
+            stamped = logged(lambda e: e[5] == "trace-fwd-regress", 2)
             assert len(stamped) == 2  # follower hop + leader hop
         finally:
             hub.stop()
