@@ -1025,90 +1025,6 @@ def _failover_figure(n_nodes: int = 8, rounds: int = 5) -> dict:
     }
 
 
-def _microtick_profile_figure(n_pods: int = 24) -> dict:
-    """ISSUE 13: duty-cycle / overlap-efficiency figures from a LIVE
-    micro-tick daemon (utils/profiler.py, fed by the pipelined
-    incremental scheduler) — an in-process trickle so every pod gets
-    its own tick, read back as the p50/p99 of the two ratio series the
-    acceptance gate pins in this artifact."""
-    from kubernetes_tpu.client import Client, LocalTransport
-    from kubernetes_tpu.scheduler.daemon import (
-        IncrementalBatchScheduler,
-        SchedulerConfig,
-    )
-    from kubernetes_tpu.server.api import APIServer
-    from kubernetes_tpu.utils import profiler
-
-    def node_wire(j):
-        return {
-            "kind": "Node", "metadata": {"name": f"prof-n{j}"},
-            "status": {
-                "capacity": {"cpu": "16", "memory": "32Gi", "pods": "110"},
-                "conditions": [{"type": "Ready", "status": "True"}],
-            },
-        }
-
-    def pod_wire(name):
-        return {
-            "kind": "Pod",
-            "metadata": {"name": name, "namespace": "default"},
-            "spec": {"containers": [{
-                "name": "c", "image": "pause",
-                "resources": {"limits": {"cpu": "50m", "memory": "32Mi"}},
-            }]},
-        }
-
-    # Fresh measurement window: earlier bench segments (churn / bulk /
-    # apichurn) drove incremental daemons in this process and fed the
-    # same process-global series — without a reset the "trickle"
-    # quantiles would read back the saturated churn distribution.
-    profiler.DUTY_CYCLE.reset()
-    profiler.OVERLAP.reset()
-    busy_base = profiler.DEVICE_BUSY.value()
-    api = APIServer()
-    client = Client(LocalTransport(api))
-    for j in range(4):
-        client.create("nodes", node_wire(j))
-    cfg = SchedulerConfig(Client(LocalTransport(api))).start()
-    cfg.wait_for_sync(60)
-    sched = IncrementalBatchScheduler(cfg, prewarm_buckets=64)
-    bound = 0
-    try:
-        sched.prewarm()
-        sched.start()
-        for i in range(n_pods):
-            client.create("pods", pod_wire(f"prof-p{i}"))
-            time.sleep(0.05)
-        deadline = time.monotonic() + 60
-        while time.monotonic() < deadline:
-            pods, _ = client.list("pods", namespace="default")
-            bound = sum(1 for p in pods if p.spec.node_name)
-            if bound >= n_pods:
-                break
-            time.sleep(0.1)
-    finally:
-        sched.stop()
-        cfg.stop()
-    fig = {
-        "microtick_profile_pods_bound": bound,
-        "scheduler_device_busy_seconds_total": round(
-            profiler.DEVICE_BUSY.value() - busy_base, 4
-        ),
-    }
-    # NaN-guarded like phase_p50_s: an empty series must not poison
-    # the JSON record.
-    for key, hist in (
-        ("scheduler_device_duty_cycle", profiler.DUTY_CYCLE),
-        ("scheduler_overlap_efficiency", profiler.OVERLAP),
-    ):
-        p50, p99 = hist.quantile(0.5), hist.quantile(0.99)
-        if p50 == p50:
-            fig[f"{key}_p50"] = round(p50, 4)
-        if p99 == p99:
-            fig[f"{key}_p99"] = round(p99, 4)
-    return fig
-
-
 def _capacity_figure(n_pods: int = 32) -> dict:
     """ISSUE 16: capacity & fragmentation figures from a LIVE
     micro-tick daemon — an in-process cluster loaded to a meaningful
@@ -1930,9 +1846,6 @@ def main() -> None:
         )
         # Sinkhorn's winning regime (VERDICT r4 #9).
         record.update(_hotspot_figure())
-        # Device duty-cycle / overlap from a live micro-tick daemon
-        # (ISSUE 13 acceptance: both series appear in the artifact).
-        record.update(_microtick_profile_figure())
         # Capacity & fragmentation plane (ISSUE 16 acceptance:
         # fragmentation_score / slice_alloc_success_rate appear in the
         # artifact).

@@ -18,7 +18,7 @@ from typing import Any, Callable, Dict, List, Optional
 
 from kubernetes_tpu.server.api import APIError
 from kubernetes_tpu.store.watch import ADDED, DELETED, ERROR, MODIFIED
-from kubernetes_tpu.utils import sanitizer
+from kubernetes_tpu.utils import sanitizer, tracing
 
 
 def meta_namespace_key(obj) -> str:
@@ -234,9 +234,17 @@ class UndeltaStore(ThreadSafeStore):
 
 class FIFO:
     """Producer/consumer queue with key-dedup: a Pop returns the latest
-    version of each enqueued object (reference: cache.FIFO, fifo.go:49-184)."""
+    version of each enqueued object (reference: cache.FIFO, fifo.go:49-184).
 
-    def __init__(self, key_func: Callable = meta_namespace_key):
+    ``wait_phase`` names a utils/tracing phase that each pop observes:
+    the time since the key's first add (an update keeps the stamp, an
+    add after the pop — a requeue — stamps anew)."""
+
+    def __init__(
+        self,
+        key_func: Callable = meta_namespace_key,
+        wait_phase: Optional[str] = None,
+    ):
         self._lock = sanitizer.lock("informer.fifo")
         self._cond = threading.Condition(self._lock)
         self._items: Dict[str, Any] = {}
@@ -244,6 +252,8 @@ class FIFO:
         self._closed = False
         self._wakes: List = []
         self.key_func = key_func
+        self._wait_phase = wait_phase
+        self._stamps: Dict[str, float] = {}
 
     def attach_wake(self, event) -> None:
         """Register a threading.Event set whenever the queue gains
@@ -265,6 +275,8 @@ class FIFO:
         with self._cond:
             if key not in self._items:
                 self._queue.append(key)
+                if self._wait_phase is not None:
+                    self._stamps[key] = time.monotonic()
             self._items[key] = obj
             self._cond.notify()
             self._signal_locked()
@@ -275,6 +287,7 @@ class FIFO:
         key = self.key_func(obj)
         with self._cond:
             self._items.pop(key, None)
+            self._stamps.pop(key, None)
             # Lazy removal: Pop skips keys without items.
 
     def pop(self, timeout: Optional[float] = None):
@@ -285,6 +298,11 @@ class FIFO:
                 while self._queue:
                     key = self._queue.pop(0)
                     if key in self._items:
+                        stamp = self._stamps.pop(key, None)
+                        if stamp is not None:
+                            tracing.observe_wait(
+                                self._wait_phase, time.monotonic() - stamp
+                            )
                         return self._items.pop(key)
                 if self._closed:
                     return None
@@ -299,6 +317,11 @@ class FIFO:
         with self._cond:
             self._items = {self.key_func(o): o for o in objs}
             self._queue = list(self._items.keys())
+            if self._wait_phase is not None:
+                now = time.monotonic()
+                self._stamps = {
+                    k: self._stamps.get(k, now) for k in self._items
+                }
             self._cond.notify_all()
             self._signal_locked()
 

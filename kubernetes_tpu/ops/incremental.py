@@ -100,8 +100,7 @@ class PendingSolve:
 
     __slots__ = (
         "_session", "pending", "assignment", "tele",
-        "dispatch_s", "block_s", "dispatched_mono", "resolved_mono",
-        "_done", "_result",
+        "dispatch_s", "block_s", "_done", "_result",
     )
 
     def __init__(self, session, pending, assignment, tele, dispatch_s):
@@ -110,12 +109,7 @@ class PendingSolve:
         self.assignment = assignment
         self.tele = tele  # (waves, sinkhorn_iters, sinkhorn_residual)
         self.dispatch_s = dispatch_s
-        self.block_s = 0.0
-        # Duty-cycle accounting (utils/profiler.py): the in-flight
-        # window is dispatched_mono -> resolved_mono; block_s of it is
-        # host time spent blocked in result().
-        self.dispatched_mono = time.monotonic()
-        self.resolved_mono = 0.0
+        self.block_s = 0.0  # host time spent blocked in result()
         self._done = assignment is None
         self._result: List[Tuple[str, Optional[str]]] = []
 
@@ -587,8 +581,7 @@ class SolverSession:
             self._pod_node[lp.key] = j
             self._apply_commit_host(j, lp)
             out.append((lp.key, self.node_names[j]))
-        handle.resolved_mono = time.monotonic()
-        handle.block_s = handle.resolved_mono - t0
+        handle.block_s = time.monotonic() - t0
         handle._result = out
         handle._done = True
 

@@ -40,7 +40,6 @@ from kubernetes_tpu.utils import (
     faults,
     flightrecorder,
     metrics,
-    profiler,
     sanitizer,
     sli,
     tracing,
@@ -162,7 +161,8 @@ class SchedulerConfig:
         # Unassigned pods -> FIFO (factory.go:180-186, field selector
         # "spec.nodeName="). DELETED events (pod bound or removed) only
         # need the key to drop the FIFO entry — skip their decode.
-        self.pod_queue = FIFO()
+        # Each pop observes the pod's wait since it was queued.
+        self.pod_queue = FIFO(wait_phase="sched.queue_wait")
         self._pod_reflector = Reflector(
             client,
             "pods",
@@ -1526,9 +1526,6 @@ class IncrementalBatchScheduler(BatchScheduler):
             maxlen=4
         )
         self._last_busy_mono = 0.0
-        # Duty-cycle baseline: when the previous tick's solve resolved
-        # (utils/profiler.py — the tick "period" is resolve-to-resolve).
-        self._last_tick_resolved_mono = 0.0
         # The dispatched-but-unresolved tick: (PendingSolve, ctx).
         self._inflight = None
         self._inflight_keys: frozenset = frozenset()
@@ -1679,7 +1676,6 @@ class IncrementalBatchScheduler(BatchScheduler):
         handle, ctx = inflight
         try:
             results = handle.result()
-            self._observe_device_profile(handle)
         except Exception:
             # Device/readback failure mid-pipeline: invalidate the
             # session and send the tick's pods back through the queue
@@ -1700,29 +1696,6 @@ class IncrementalBatchScheduler(BatchScheduler):
             prefer_inline=prefer_inline,
         )
         return len(ctx["pending"])
-
-    def _observe_device_profile(self, handle) -> None:
-        """Per-tick device-time accounting (utils/profiler.py): the
-        in-flight window (solve dispatch -> PendingSolve.result()) over
-        the resolve-to-resolve tick period gives the duty cycle; the
-        blocked readback share of that window gives the realized
-        solve/commit overlap. Empty handles (idle flushes) observe
-        nothing — they had no device work to account."""
-        if not handle.pending:
-            return
-        start = getattr(handle, "dispatched_mono", 0.0)
-        end = getattr(handle, "resolved_mono", 0.0)
-        if not start or not end or end <= start:
-            return
-        prev = self._last_tick_resolved_mono
-        self._last_tick_resolved_mono = end
-        if not prev or end <= prev:
-            # First tick (or clock wobble): no period to divide by —
-            # baseline only. Observing device_s/device_s here would
-            # inject a phantom 1.0 duty sample per daemon instance,
-            # which a short run's p99 then reads as full saturation.
-            return
-        profiler.observe_tick(end - start, end - prev, handle.block_s)
 
     def _finish_tick(
         self, session, results, ctx, solve_s, prefer_inline=False
@@ -2192,7 +2165,6 @@ class IncrementalBatchScheduler(BatchScheduler):
                 self._inflight_keys = frozenset(handle.keys)
                 return len(pending) + len(deferred)
             results = handle.result()
-            self._observe_device_profile(handle)
             self._finish_tick(
                 self._session, results, ctx,
                 ctx["stage_s"] + handle.dispatch_s + handle.block_s,

@@ -762,8 +762,15 @@ class _Handler(BaseHTTPRequestHandler):
         # Reset per request: keep-alive connections reuse this handler
         # instance, and a prior request's version must not leak.
         self.wire_version = "v1"
+        timed = None
         try:
             parts = self._route()
+            if not _request_is_long_running(parts, self.query):
+                # api.request: decode, admission, registry, store and
+                # encode of one request. A watch (or relay) holds its
+                # handler for its lifetime and is left out.
+                timed = tracing.phase("api.request")
+                timed.__enter__()
             if parts == ("healthz",):
                 self._serve_healthz()
                 return
@@ -885,6 +892,8 @@ class _Handler(BaseHTTPRequestHandler):
             except Exception:  # ktlint: disable=KT003
                 pass  # client already gone; the 500 has nowhere to go
         finally:
+            if timed is not None:
+                timed.__exit__(None, None, None)
             duration = time.monotonic() - start
             _REQS.inc(verb=verb, resource=resource, code=str(code))
             _LATENCY.observe(duration, verb=verb, resource=resource)

@@ -53,7 +53,7 @@ from collections import deque
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from kubernetes_tpu.store.watch import ADDED, DELETED, Event, MODIFIED, WatchStream
-from kubernetes_tpu.utils import faults, sanitizer
+from kubernetes_tpu.utils import faults, sanitizer, tracing
 
 
 class StoreError(Exception):
@@ -800,7 +800,9 @@ class KVStore:
         self._history.append((version, etype, key, obj))
         if len(self._history) == self._history.maxlen:
             self._oldest = self._history[0][0]
-        self._dispatch_q.put((version, etype, key, obj, prev))
+        self._dispatch_q.put(
+            (version, etype, key, obj, prev, time.monotonic())
+        )
 
     def _dispatch_event(self, item: tuple) -> None:
         """Watch fan-out for one event, off the write path. ALL watchers
@@ -822,7 +824,10 @@ class KVStore:
         only match (directly or through the DELETED translation) when
         obj or prev carries its shard value, so skipped watchers would
         have produced no event anyway."""
-        version, etype, key, obj, prev = item
+        version, etype, key, obj, prev, queued = item
+        # How long the event sat in the fan-out queue: once per event,
+        # never per watcher.
+        tracing.observe_wait("watch.fanout_wait", time.monotonic() - queued)
         for sub in self._subscribers:
             # Subscribers see every event in version order before the
             # watcher fan-out (they feed read caches, so they must be
@@ -891,10 +896,9 @@ class KVStore:
                     heapq.heappush(self._ttl_heap, (exp, key))
                     self._next_expiry = min(self._next_expiry, exp)
                 self._record_locked(v, ADDED, key, obj)
-                return self._wal_seq
+                return None, self._wal_seq
 
-        seq = self._apply_write(op)
-        self._ack_write(seq)  # fsync-before-ack, amortized across writers
+        self._commit_write(op)
         return _copy_obj(obj)
 
     def create_many(
@@ -938,9 +942,7 @@ class KVStore:
                 self._wal_flush_locked()
                 return out, self._wal_seq
 
-        results, seq = self._apply_write(op)
-        self._ack_write(seq)  # ONE fsync for the whole batch
-        return results
+        return self._commit_write(op)  # ONE fsync for the whole batch
 
     def delete_many(self, keys: List[str]) -> List:
         """Delete a batch of keys under one lock hold / WAL append /
@@ -963,9 +965,7 @@ class KVStore:
                 self._wal_flush_locked()
                 return out, self._wal_seq
 
-        results, seq = self._apply_write(op)
-        self._ack_write(seq)
-        return results
+        return self._commit_write(op)
 
     def get(self, key: str) -> dict:
         with self._lock:
@@ -998,10 +998,9 @@ class KVStore:
                 self._stamp(obj, v)
                 self._data[key] = (obj, v)
                 self._record_locked(v, MODIFIED, key, obj, prev=prev)
-                return self._wal_seq
+                return None, self._wal_seq
 
-        seq = self._apply_write(op)
-        self._ack_write(seq)
+        self._commit_write(op)
         return _copy_obj(obj)
 
     def delete(self, key: str, expected_version: Optional[int] = None) -> dict:
@@ -1021,9 +1020,7 @@ class KVStore:
                 self._record_locked(v, DELETED, key, obj)
                 return obj, self._wal_seq
 
-        obj, seq = self._apply_write(op)
-        self._ack_write(seq)
-        return _copy_obj(obj)
+        return _copy_obj(self._commit_write(op))
 
     def list(self, prefix: str, copy: bool = True) -> Tuple[List[dict], int]:
         """All objects under prefix + the store version (for watch
@@ -1047,6 +1044,17 @@ class KVStore:
         with self._lock:
             self._expire_locked()
             return sorted(k for k in self._data if k.startswith(prefix))
+
+    def _commit_write(self, op):
+        """One public write: apply ``op`` (which returns (result, WAL
+        seq)), then the durability ack for its seq. Timed as the
+        store.commit phase: lock wait, apply, WAL append, and the
+        fsync/quorum ack — one observation per write call, so one per
+        bulk request. Returns op's result."""
+        with tracing.phase("store.commit"):
+            result, seq = self._apply_write(op)
+            self._ack_write(seq)
+        return result
 
     def _apply_write(self, op):
         """Run a mutation closure directly, or through the serialized
@@ -1121,9 +1129,7 @@ class KVStore:
                 stored = self._atomic_update_locked(key, update_fn)
                 return stored, self._wal_seq
 
-        stored, seq = self._apply_write(op)
-        self._ack_write(seq)
-        return _copy_obj(stored)
+        return _copy_obj(self._commit_write(op))
 
     def atomic_update_many(
         self, ops: List[Tuple[str, Callable[[dict], dict]]],
@@ -1207,8 +1213,7 @@ class KVStore:
                 self._wal_flush_locked()
                 return out, self._wal_seq
 
-        results, seq = self._apply_write(batch)
-        self._ack_write(seq)
+        results = self._commit_write(batch)
         # copy_results=False hands back the STORED objects (read-only
         # contract) — callers that only inspect status/metadata (the
         # bind commit path, bulk update) skip a per-item json round

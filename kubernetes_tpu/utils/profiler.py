@@ -1,39 +1,16 @@
-"""Device-time profiling plane: micro-tick duty cycle, solve/commit
-overlap, and on-demand device traces.
+"""On-demand device traces, and the ratio bucket ladder.
 
 The compile/cost half of the profiling story lives in ops/ledger.py
 (it needs jax; this module must stay importable by a control-plane
-process that never touches the accelerator). Here lives the HOST-side
-accounting the micro-tick daemon feeds every tick, plus the
+process that never touches the accelerator). Here lives the
 ``jax.profiler.trace`` wrapper behind ``GET /debug/device-profile``:
-
-- **duty cycle** (``scheduler_device_duty_cycle``): the fraction of a
-  micro-tick period the device spent busy — the in-flight window from
-  solve dispatch to ``PendingSolve.result()`` over the wall between
-  consecutive tick resolutions. An idle cluster reads ~0; a saturated
-  pipelined daemon should approach 1.0. Read it against
-  ``scheduler_overlap_efficiency`` — high duty + low overlap means the
-  host is BLOCKING on the device instead of overlapping it.
-
-- **overlap efficiency** (``scheduler_overlap_efficiency``): of the
-  device-busy window, the fraction the host spent doing useful work
-  (staging tick k+1, commit I/O) rather than blocked in the readback
-  — 1 - blocked/device_busy. This is the realized value of PR 12's
-  pipelined dispatch: a fixed-tick daemon measures ~0 here.
-
-- ``scheduler_device_busy_seconds_total``: the raw busy-seconds
-  counter behind the duty ratio, so dashboards can rate() it across
-  scrape intervals.
-
-- **device traces**: ``capture_device_trace(seconds)`` wraps
-  ``jax.profiler.trace`` around a sleep on the calling (HTTP handler)
-  thread while the daemon threads keep dispatching — the produced
-  directory opens in XProf/TensorBoard or perfetto. One capture at a
-  time per process (the profiler backend cannot nest).
-
-Everything here is microseconds-per-tick host bookkeeping;
-tests/test_profiler.py pins ledger + duty accounting at <5% of the
-bulk-churn drill (the PR-9 always-on budget).
+``capture_device_trace(seconds)`` sleeps on the calling (HTTP handler)
+thread while the daemon threads keep dispatching — the produced
+directory opens in XProf/TensorBoard or perfetto. One capture at a
+time per process (the profiler backend cannot nest). The program's
+phases (utils/tracing ``kt.*`` annotations) land in the same trace, on
+the device's clock, so an idle stretch of the device reads against
+what each host thread was doing.
 """
 
 from __future__ import annotations
@@ -43,48 +20,15 @@ import tempfile
 import time
 from typing import Optional
 
-from kubernetes_tpu.utils import metrics, sanitizer
+from kubernetes_tpu.utils import sanitizer
 
-#: Ratio ladders: duty/overlap are [0, 1] by construction, so the
-#: default latency buckets would dump everything into one bucket.
+#: Bucket ladder for histograms of [0, 1] ratios (capacity scores,
+#: rebalance improvements): the default latency buckets would dump
+#: everything into one bucket.
 RATIO_BUCKETS = (
     0.01, 0.05, 0.1, 0.2, 0.3, 0.4, 0.5, 0.6, 0.7, 0.8, 0.9, 0.95,
     0.99, 1.0,
 )
-
-DUTY_CYCLE = metrics.DEFAULT.histogram(
-    "scheduler_device_duty_cycle",
-    "Fraction of a micro-tick period the solve device spent busy "
-    "(dispatch -> readback over the tick wall)",
-    buckets=RATIO_BUCKETS,
-)
-OVERLAP = metrics.DEFAULT.histogram(
-    "scheduler_overlap_efficiency",
-    "Fraction of the device-busy window the host overlapped with "
-    "useful work instead of blocking on the readback",
-    buckets=RATIO_BUCKETS,
-)
-DEVICE_BUSY = metrics.DEFAULT.counter(
-    "scheduler_device_busy_seconds_total",
-    "Total seconds the solve device spent busy (in-flight solves)",
-)
-
-
-def observe_tick(
-    device_s: float, wall_s: float, blocked_s: float
-) -> None:
-    """One resolved micro-tick's accounting: ``device_s`` is the
-    dispatch->readback in-flight window, ``wall_s`` the period since
-    the previous tick resolved, ``blocked_s`` the host time spent
-    blocked inside ``result()``. Ratios clamp to [0, 1] — monotonic
-    clock jitter must not poison a histogram bucket."""
-    if device_s <= 0.0 or wall_s <= 0.0:
-        return
-    DEVICE_BUSY.inc(device_s)
-    DUTY_CYCLE.observe(min(1.0, device_s / wall_s))
-    OVERLAP.observe(
-        min(1.0, max(0.0, 1.0 - blocked_s / device_s))
-    )
 
 
 # -- on-demand device traces -------------------------------------------

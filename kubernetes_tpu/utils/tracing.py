@@ -20,7 +20,14 @@ Model:
   under THAT id, and /debug/traces merges entries by trace id.
 - phase() is span() plus an unconditional observation into the
   scheduler_phase_seconds histogram — the always-on in-situ phase
-  breakdown bench.py publishes, independent of trace sampling.
+  breakdown of the control plane (scheduler, API, store), independent
+  of trace sampling. observe_wait() adds a wait that is not a code
+  block (an item's time in a queue) to the same histogram.
+- Every phase() and every span trace() opens also writes a
+  jax.profiler.TraceAnnotation named ``kt.<name>`` on its own thread,
+  so a device trace shows the program's phases on the device's clock.
+  Only once jax is already imported: this module never imports it
+  (control-plane processes without the accelerator use it too).
 
 Disabled tracing (configure(sample_rate=0)) costs one contextvar read
 and one RNG draw per trace() call and nothing per span(); the hot
@@ -34,6 +41,7 @@ import json
 import logging
 import os
 import random
+import sys
 import threading
 import time
 from typing import Dict, Iterable, List, Optional
@@ -46,16 +54,44 @@ _LOG = logging.getLogger("kubernetes_tpu.trace")
 #: entries re-parent by trace id at render time).
 TRACE_HEADER = "X-Trace-Id"
 
-#: In-situ per-phase latency of the batched solve pipeline. Always
-#: observed (even with tracing sampled out) — this is the histogram
-#: bench.py reads back after the headline run. Note: JAX dispatch is
-#: async, so in pipelined mode "solve" measures dispatch and the
-#: device time accrues to "readback" (the blocking copy-out).
+#: In-situ per-phase latency of the control plane: the scheduler's
+#: solve pipeline, the API front door (api.request), the store
+#: (store.commit, watch.fanout_wait) and the pod queue
+#: (sched.queue_wait). Always observed (even with tracing sampled
+#: out). Note: JAX dispatch is async, so in pipelined mode "solve"
+#: measures dispatch and the device time accrues to "readback" (the
+#: blocking copy-out).
 PHASE_SECONDS = metrics.DEFAULT.histogram(
     "scheduler_phase_seconds",
-    "Latency of one solve-pipeline phase (lower/upload/solve/readback/bind)",
+    "Latency of one in-process phase of the control plane "
+    "(scheduler, API, store)",
     ("phase",),
 )
+
+#: Prefix of the profiler annotations phases and traces write.
+ANNOTATION_PREFIX = "kt."
+
+# jax.profiler.TraceAnnotation, resolved on first use after jax loads.
+_annotation_cls = None
+
+
+def _annotate(name: str, pods=None):
+    """Open a ``kt.<name>`` profiler annotation on this thread and
+    return it, or None while jax is not imported. Never imports jax."""
+    global _annotation_cls
+    cls = _annotation_cls
+    if cls is None:
+        prof = getattr(sys.modules.get("jax"), "profiler", None)
+        cls = _annotation_cls = getattr(prof, "TraceAnnotation", None)
+        if cls is None:
+            return None
+    ann = (
+        cls(ANNOTATION_PREFIX + name) if pods is None
+        else cls(ANNOTATION_PREFIX + name, pods=pods)
+    )
+    ann.__enter__()
+    return ann
+
 
 _RNG = random.Random()
 
@@ -286,13 +322,14 @@ class _TraceCtx:
     """Context manager behind trace(): owns a root Trace, or joins the
     active trace as a child span."""
 
-    __slots__ = ("_trace", "_span", "_tok_trace", "_tok_span")
+    __slots__ = ("_trace", "_span", "_tok_trace", "_tok_span", "_ann")
 
     def __init__(self, trace: Optional[Trace], join_span: Optional[Span]):
         self._trace = trace
         self._span = trace.root if trace is not None else join_span
         self._tok_trace = None
         self._tok_span = None
+        self._ann = None
 
     def __enter__(self) -> Span:
         if self._span is None:
@@ -300,11 +337,14 @@ class _TraceCtx:
         if self._trace is not None:
             self._tok_trace = _current_trace.set(self._trace)
         self._tok_span = _current_span.set(self._span)
+        self._ann = _annotate(self._span.name)
         return self._span
 
     def __exit__(self, *exc) -> bool:
         if self._span is None:
             return False
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
         self._span.finish()
         if self._tok_span is not None:
             _current_span.reset(self._tok_span)
@@ -366,16 +406,20 @@ def trace(name: str, trace_id: Optional[str] = None, pod: Optional[str] = None,
 
 
 class _SpanCtx:
-    __slots__ = ("_span", "_tok", "_phase", "_t0")
+    __slots__ = ("_span", "_tok", "_phase", "_pods", "_t0", "_ann")
 
-    def __init__(self, span: Optional[Span], phase: Optional[str]):
+    def __init__(self, span: Optional[Span], phase: Optional[str],
+                 pods=None):
         self._span = span
         self._phase = phase
+        self._pods = pods
         self._tok = None
         self._t0 = 0.0
+        self._ann = None
 
     def __enter__(self):
         if self._phase is not None:
+            self._ann = _annotate(self._phase, self._pods)
             self._t0 = time.monotonic()
         if self._span is None:
             return NULL_SPAN
@@ -387,6 +431,8 @@ class _SpanCtx:
             PHASE_SECONDS.observe(
                 time.monotonic() - self._t0, phase=self._phase
             )
+            if self._ann is not None:
+                self._ann.__exit__(None, None, None)
         if self._span is not None:
             self._span.finish()
             _current_span.reset(self._tok)
@@ -402,10 +448,18 @@ def span(name: str, **fields) -> _SpanCtx:
 
 
 def phase(name: str, **fields) -> _SpanCtx:
-    """span() + unconditional scheduler_phase_seconds observation."""
+    """span() + unconditional scheduler_phase_seconds observation + a
+    ``kt.<name>`` profiler annotation (carrying ``pods`` when given)."""
     parent = _current_span.get()
     sp = parent.child(name, **fields) if parent is not None else None
-    return _SpanCtx(sp, name)
+    return _SpanCtx(sp, name, fields.get("pods"))
+
+
+def observe_wait(name: str, seconds: float) -> None:
+    """A wait that is not a code block — how long an item sat in a
+    queue — observed into scheduler_phase_seconds under ``name``. No
+    span and no annotation: the waiting happened on no one thread."""
+    PHASE_SECONDS.observe(seconds, phase=name)
 
 
 # -- rendering (shared by the LogIfLong dump and `ktctl trace`) --------

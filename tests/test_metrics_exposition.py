@@ -427,20 +427,13 @@ def test_sli_metrics_exposed():
 
 
 def test_lint_metrics_knows_profiler_names(tmp_path):
-    """The device-time profiling-plane family (ops/ledger.py,
-    utils/profiler.py) is known to the linter: the _total-suffixed
-    counters pass the standard rule on their own, the unit-less
-    duty-cycle/overlap ratio histograms are explicitly allowlisted,
-    and a novel suffix-less profiler name still fails (the allowlist
-    names metrics, not a prefix)."""
+    """The device-time profiling-plane family (ops/ledger.py) is known
+    to the linter: the _total-suffixed compile counter passes the
+    standard rule on its own, and a novel suffix-less profiler name
+    still fails (the allowlist names metrics, not a prefix)."""
     from tools.ktlint.rules_metrics import ALLOWLIST, PROFILER_METRICS
 
-    assert PROFILER_METRICS == {
-        "solver_compile_seconds_total",
-        "scheduler_device_busy_seconds_total",
-        "scheduler_device_duty_cycle",
-        "scheduler_overlap_efficiency",
-    }
+    assert PROFILER_METRICS == {"solver_compile_seconds_total"}
     assert PROFILER_METRICS <= ALLOWLIST
     root = pathlib.Path(__file__).resolve().parent.parent
     good = tmp_path / "good"
@@ -449,10 +442,6 @@ def test_lint_metrics_knows_profiler_names(tmp_path):
         "from kubernetes_tpu.utils import metrics\n"
         'A = metrics.DEFAULT.counter('
         '"solver_compile_seconds_total", "x", ("kernel",))\n'
-        'B = metrics.DEFAULT.histogram("scheduler_device_duty_cycle", "x")\n'
-        'C = metrics.DEFAULT.histogram("scheduler_overlap_efficiency", "x")\n'
-        'D = metrics.DEFAULT.counter('
-        '"scheduler_device_busy_seconds_total", "x")\n'
     )
     proc = _ktlint_kt005(root, good)
     assert proc.returncode == 0, proc.stderr
@@ -468,25 +457,12 @@ def test_lint_metrics_knows_profiler_names(tmp_path):
 
 
 def test_profiler_metrics_exposed():
-    """Exposition golden for the profiling-plane family: the duty/
-    overlap ratio histograms render cumulative +le buckets on their
-    ratio ladder, and the compile-seconds counter escapes hostile
-    kernel label values."""
-    from kubernetes_tpu.utils import profiler
-
-    profiler.observe_tick(device_s=0.004, wall_s=0.01, blocked_s=0.001)
+    """Exposition golden for the profiling-plane family: the
+    compile-seconds counter escapes hostile kernel label values."""
     from kubernetes_tpu.ops import ledger
 
     ledger.COMPILE_SECONDS.inc(1.5, kernel='we"ird\\kern\nx')
     text = metrics.DEFAULT.render()
-    assert "# TYPE scheduler_device_duty_cycle histogram" in text
-    # 0.4 duty lands at le=0.4 of the ratio ladder; buckets cumulate
-    # to the +Inf == count invariant.
-    assert 'scheduler_device_duty_cycle_bucket{le="0.4"}' in text
-    assert 'scheduler_device_duty_cycle_bucket{le="+Inf"}' in text
-    assert "# TYPE scheduler_overlap_efficiency histogram" in text
-    assert 'scheduler_overlap_efficiency_bucket{le="0.8"}' in text
-    assert "# TYPE scheduler_device_busy_seconds_total counter" in text
     assert "# TYPE solver_compile_seconds_total counter" in text
     # Label escaping: a hostile kernel name can never corrupt the
     # exposition.

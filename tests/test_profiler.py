@@ -5,12 +5,11 @@ Covers: the traced-jit compile ledger (detection via the PR-7
 growth without double-counting cached compiles), ledger completeness
 against the KT006 ``ORACLE_TWINS`` registry (the acceptance gate:
 every registered jitted kernel that ran has a ledger row with compile
-time + cost analysis), duty-cycle/overlap series from a live
-micro-tick daemon, the ``ktctl profile`` miss/populated exit contract,
-the HTTP surfaces (``/debug/kernels``, ``/debug/profile?format=
-collapsed``, ``/debug/device-profile``), and the overhead guard
-pinning ledger + duty-cycle accounting at <5% of the bulk-churn drill
-(the PR-9 always-on budget)."""
+time + cost analysis), the ``ktctl profile`` miss/populated exit
+contract, the HTTP surfaces (``/debug/kernels``, ``/debug/profile?
+format=collapsed``, ``/debug/device-profile``), and the overhead guard
+pinning ledger accounting at <5% of the bulk-churn drill (the
+always-on budget)."""
 
 import io
 import json
@@ -280,63 +279,6 @@ class TestLedgerCompleteness:
             )
 
 
-class TestDutyCycle:
-    def test_live_microtick_daemon_populates_series(self):
-        """A started micro-tick daemon binding real pods observes one
-        duty-cycle + overlap sample per resolved tick, with ratio
-        values inside [0, 1] and busy-seconds accumulating."""
-        from kubernetes_tpu.client import Client, LocalTransport
-        from kubernetes_tpu.scheduler.daemon import (
-            IncrementalBatchScheduler,
-            SchedulerConfig,
-        )
-        from kubernetes_tpu.server.api import APIServer
-
-        duty0 = profiler.DUTY_CYCLE.count()
-        over0 = profiler.OVERLAP.count()
-        busy0 = profiler.DEVICE_BUSY.value()
-        api = APIServer()
-        client = Client(LocalTransport(api))
-        client.create("nodes", node_wire("n0"))
-        cfg = SchedulerConfig(Client(LocalTransport(api))).start()
-        assert cfg.wait_for_sync()
-        sched = IncrementalBatchScheduler(cfg, prewarm_buckets=128)
-        sched.prewarm()
-        sched.start()
-        try:
-            n = 5
-            for i in range(n):
-                client.create("pods", pod_wire(f"duty-{i}"))
-                time.sleep(0.1)
-            deadline = time.monotonic() + 60
-            while time.monotonic() < deadline:
-                pods, _ = client.list("pods", namespace="default")
-                if sum(1 for p in pods if p.spec.node_name) == n:
-                    break
-                time.sleep(0.05)
-            assert sum(1 for p in pods if p.spec.node_name) == n
-        finally:
-            sched.stop()
-            cfg.stop()
-        assert profiler.DUTY_CYCLE.count() - duty0 >= 1
-        assert profiler.OVERLAP.count() - over0 >= 1
-        assert profiler.DEVICE_BUSY.value() > busy0
-        # Ratio ladders: every observation landed in a finite bucket
-        # (values are clamped to [0, 1] <= the top bound).
-        for h in (profiler.DUTY_CYCLE, profiler.OVERLAP):
-            assert h.quantile(0.99) <= 1.0
-
-    def test_observe_tick_clamps(self):
-        base = profiler.DUTY_CYCLE.count()
-        # Clock jitter making device > wall or blocked > device must
-        # clamp into [0, 1], and degenerate ticks observe nothing.
-        profiler.observe_tick(2.0, 1.0, 5.0)
-        profiler.observe_tick(0.0, 1.0, 0.0)
-        profiler.observe_tick(1.0, 0.0, 0.0)
-        assert profiler.DUTY_CYCLE.count() == base + 1
-        assert profiler.DUTY_CYCLE.quantile(1.0) <= 1.0
-
-
 class TestKtctlProfile:
     def test_kernels_miss_contract_on_cold_process(self, monkeypatch, capsys):
         """`ktctl profile kernels` on a process with no compiles: exit
@@ -552,7 +494,7 @@ class TestCollapsedFormatUnit:
 
 
 class TestOverheadGuard:
-    """Always-on observability must be affordable: the ledger + duty
+    """Always-on observability must be affordable: the ledger
     accounting added per tick is pinned at <5% of the bulk-churn
     drill's wall (the PR-9 SLI guard's shape)."""
 
@@ -606,8 +548,8 @@ class TestOverheadGuard:
         # Standalone cost of the profiling plane at a density far
         # beyond reality: one traced-jit call bookkeeping per pod
         # EVENT (a real tick batches hundreds of pods into ~4 kernel
-        # dispatches), one duty/overlap observation per batch, plus a
-        # full ledger render per batch (the /debug/kernels scrape).
+        # dispatches), plus a full ledger render per batch (the
+        # /debug/kernels scrape).
         # Best of three repeats: a GC pause inside one repeat must not
         # fail the guard.
         led = ledger.CompileLedger()
@@ -618,7 +560,6 @@ class TestOverheadGuard:
             for _ in range(2 * n_pods):
                 led.note_call("solver._solve_with_state_xla")
             for _ in range(2 * n_pods // batch):
-                profiler.observe_tick(0.002, 0.01, 0.001)
                 led.summary()
             cost = min(cost, time.perf_counter() - t0)
         assert cost < 0.05 * drill_wall, (

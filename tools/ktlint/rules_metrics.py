@@ -100,19 +100,12 @@ SLI_METRICS = {
 }
 ALLOWLIST |= SLI_METRICS
 
-#: Device-time profiling-plane family (ops/ledger.py,
-#: utils/profiler.py, scheduler/daemon.py — see docs/performance.md
-#: "Profiling the solve path"). solver_compile_seconds_total and
-#: scheduler_device_busy_seconds_total carry standard suffixes on
-#: their own; the duty-cycle and overlap-efficiency histograms are
-#: unit-less [0, 1] ratios observed into ratio bucket ladders and are
-#: allowlisted explicitly so the linter documents the whole family
-#: rather than silently tolerating it.
+#: Device-time profiling-plane family (ops/ledger.py — see
+#: docs/performance.md "Profiling the solve path").
+#: solver_compile_seconds_total carries a standard suffix on its own;
+#: it is declared so the linter documents the family.
 PROFILER_METRICS = {
     "solver_compile_seconds_total",
-    "scheduler_device_busy_seconds_total",
-    "scheduler_device_duty_cycle",
-    "scheduler_overlap_efficiency",
 }
 ALLOWLIST |= PROFILER_METRICS
 
