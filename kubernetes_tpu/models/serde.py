@@ -126,7 +126,7 @@ def _copy_raw(v: Any) -> Any:
     """Deep-copy raw (untyped) wire leaves. Any-typed fields (e.g.
     ContainerStatus.state) would otherwise alias the source dict —
     and store watch events share ONE object across all watchers
-    (kvstore._dispatch_event), so an aliased leaf mutated by one
+    (kvstore._dispatch_batch), so an aliased leaf mutated by one
     informer consumer would silently corrupt every other's view."""
     if isinstance(v, dict):
         return {k: _copy_raw(x) for k, x in v.items()}

@@ -39,7 +39,9 @@ from kubernetes_tpu.server.allocators import (
     service_ips_in_use,
     service_node_ports_in_use,
 )
-from kubernetes_tpu.server.registry import RESOURCES, ResourceInfo, fields_for
+from kubernetes_tpu.server.registry import (
+    RESOURCES, ResourceInfo, field_extractor,
+)
 from kubernetes_tpu.store import (
     AlreadyExistsError,
     ConflictError,
@@ -101,6 +103,35 @@ def _watch_shard(resource: str, field_selector: str):
             if fn is not None:
                 return (fn, value)
     return None
+
+
+#: The default and the largest depth of a watch's event queue (see
+#: APIServer.watch).
+WATCH_QUEUE_MAX = 65536
+
+
+class _SelectorPred:
+    """A label and field selector as an object predicate. It exposes
+    its field extractor (`extract`, shared per resource) and
+    `matches(obj, fields)`, so the store's watch fan-out extracts an
+    object's fields once for every watcher that tests it
+    (KVStore.watch)."""
+
+    __slots__ = ("lsel", "fsel", "extract")
+
+    def __init__(self, lsel, fsel, extract):
+        self.lsel = lsel
+        self.fsel = fsel
+        self.extract = None if fsel.empty() else extract
+
+    def __call__(self, o: dict) -> bool:
+        return self.matches(o, None if self.extract is None else self.extract(o))
+
+    def matches(self, o: dict, fields) -> bool:
+        if not self.lsel.empty():
+            if not self.lsel.matches(o.get("metadata", {}).get("labels", {})):
+                return False
+        return fields is None or self.fsel.matches(fields)
 
 
 def _not_found(resource: str, name: str) -> APIError:
@@ -867,17 +898,7 @@ class APIServer:
         fsel = labelpkg.parse_fields(field_selector)
         if lsel.empty() and fsel.empty():
             return lambda o: True
-
-        def pred(o: dict) -> bool:
-            if not lsel.empty():
-                if not lsel.matches(o.get("metadata", {}).get("labels", {})):
-                    return False
-            if not fsel.empty():
-                if not fsel.matches(fields_for(resource, o)):
-                    return False
-            return True
-
-        return pred
+        return _SelectorPred(lsel, fsel, field_extractor(resource))
 
     def update(self, resource: str, namespace: str, name: str, obj: dict) -> dict:
         info = self._info(resource)
@@ -1389,7 +1410,7 @@ class APIServer:
         since: int = 0,
         label_selector: str = "",
         field_selector: str = "",
-        maxsize: int = 4096,
+        maxsize: int = WATCH_QUEUE_MAX,
     ) -> WatchStream:
         """Selector filtering happens INSIDE the store's fan-out (with
         etcd's modified-out-of-filter -> DELETED translation,
@@ -1397,10 +1418,14 @@ class APIServer:
         has the other nodes' pod events copied or queued for it.
 
         `maxsize` bounds the consumer's event queue (slow consumers are
-        dropped at overflow and must re-list); bulk-churn clients ask
-        for deeper buffers (?maxsize=) so a single group commit's burst
-        of N events cannot out-run one scheduling quantum of their
-        reader."""
+        dropped at overflow and must re-list), clamped to [1024,
+        WATCH_QUEUE_MAX]. The default is the largest: the store's
+        dispatcher hands a stream a whole backlog per pass (a bulk
+        delete of 10,000 pods is 10,000 events at once), and a reader
+        that shares its interpreter with the apiserver takes them at its
+        own pace; the queue is its room to catch up in. A stream dropped
+        mid-burst resumes or re-lists, and meanwhile its client's other
+        watches run ahead of it."""
         info = self._info(resource)
         pred = None
         shard = None
@@ -1410,7 +1435,7 @@ class APIServer:
         try:
             return self.store.watch(
                 info.prefix(namespace), since=since, pred=pred, shard=shard,
-                maxsize=max(1024, min(int(maxsize), 65536)),
+                maxsize=max(1024, min(int(maxsize), WATCH_QUEUE_MAX)),
             )
         except Exception as e:  # CompactedError -> 410 Gone
             raise APIError(410, "Expired", str(e))

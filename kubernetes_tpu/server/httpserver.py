@@ -30,7 +30,7 @@ from urllib.parse import parse_qs, urlparse
 
 from kubernetes_tpu import __version__
 from kubernetes_tpu.models import conversion
-from kubernetes_tpu.server.api import APIError, APIServer
+from kubernetes_tpu.server.api import WATCH_QUEUE_MAX, APIError, APIServer
 from kubernetes_tpu.server.registry import RESOURCES
 from kubernetes_tpu.utils import metrics, sli, tracing
 
@@ -1553,7 +1553,7 @@ class _Handler(BaseHTTPRequestHandler):
         try:
             since = int(q.get("resourceVersion", "0") or "0")
             timeout = float(q.get("timeoutSeconds", "0") or "0") or None
-            maxsize = int(q.get("maxsize", "4096") or "4096")
+            maxsize = int(q.get("maxsize") or WATCH_QUEUE_MAX)
         except ValueError:
             raise APIError(
                 400, "BadRequest",

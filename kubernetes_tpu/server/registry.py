@@ -191,5 +191,7 @@ FIELD_EXTRACTORS: Dict[str, Callable[[dict], Dict[str, str]]] = {
 }
 
 
-def fields_for(resource: str, obj: dict) -> Dict[str, str]:
-    return FIELD_EXTRACTORS.get(resource, generic_fields)(obj)
+def field_extractor(resource: str) -> Callable[[dict], Dict[str, str]]:
+    """The resource's field extractor: one shared function per
+    resource, so callers can key a cache of fields on it."""
+    return FIELD_EXTRACTORS.get(resource, generic_fields)

@@ -66,7 +66,7 @@ class ResourceCache:
         # Seed from the store's current state; events that raced in are
         # buffered by the set's _BufferingRoute and replayed after (the
         # route registers BEFORE this list, so nothing can be missed —
-        # apply() drops versions the seed already covered).
+        # apply_many() drops versions the seed already covered).
         objs, at = store.list(prefix, copy=False)
         with self._lock:
             for obj in objs:
@@ -88,22 +88,25 @@ class ResourceCache:
 
     # -- event feed (dispatcher thread) --------------------------------
 
-    def apply(self, version: int, etype: str, key: str, obj: dict) -> None:
+    def apply_many(self, events) -> None:
+        """Apply (version, etype, key, obj) events, in order, under one
+        lock hold."""
         with self._lock:
-            if etype == "DELETED":
-                # Version-guarded like the upsert branch: a stale
-                # buffered DELETED replayed during seeding must not
-                # remove a NEWER recreated object the seed captured.
-                cur = self._items.get(key)
-                if cur is not None and version >= cur.version:
-                    del self._items[key]
-                    self._sorted = None
-            else:
-                cur = self._items.get(key)
-                if cur is None:
-                    self._sorted = None
-                if cur is None or version >= cur.version:
-                    self._items[key] = _Entry(obj, version)
+            items = self._items
+            for version, etype, key, obj in events:
+                cur = items.get(key)
+                if etype == "DELETED":
+                    # Version-guarded like the upsert branch: a stale
+                    # buffered DELETED replayed during seeding must not
+                    # remove a NEWER recreated object the seed captured.
+                    if cur is not None and version >= cur.version:
+                        del items[key]
+                        self._sorted = None
+                else:
+                    if cur is None:
+                        self._sorted = None
+                    if cur is None or version >= cur.version:
+                        items[key] = _Entry(obj, version)
 
     # -- consistency ---------------------------------------------------
 
@@ -151,9 +154,9 @@ class ResourceCache:
     def _snapshot_entries_locked_free(self, prefix: str) -> List[_Entry]:
         """Consistent entry snapshot under a SHORT lock hold. The
         per-object work (selector filtering, lazy encoding) happens
-        OUTSIDE the lock: the dispatcher thread needs it for apply(),
-        so a large LIST must not stall watch fan-out for the duration
-        of thousands of json.dumps calls. Entries are immutable per
+        OUTSIDE the lock: the dispatcher thread needs it for
+        apply_many(), so a large LIST must not stall watch fan-out for
+        the duration of thousands of json.dumps calls. Entries are immutable per
         version and `enc` writes are idempotent (bytes deterministic
         per resourceVersion), so the unlocked access is benign."""
         with self._lock:
@@ -236,21 +239,30 @@ class WatchCacheSet:
         # with every pod write's version (false SLO warns). Plain dict:
         # single writer (the dispatcher), GIL-atomic reads.
         self._applied_by_resource: Dict[str, int] = {}
-        store.subscribe(self._on_event)
+        store.subscribe(many=self._on_events)
 
-    def _on_event(
-        self, version: int, etype: str, key: str, obj: dict, prev
-    ) -> None:
-        for prefix, cache in self._routes:
-            if key.startswith(prefix):
-                cache.apply(version, etype, key, obj)
-                break
-        # key shape '/registry/<resource>/...' — split bounded at 3.
-        parts = key.split("/", 3)
-        if len(parts) > 2:
-            self._applied_by_resource[parts[2]] = version
+    def _on_events(self, items) -> None:
+        """One dispatcher pass, in version order: each resource cache
+        applies its share under one lock hold, then `applied` moves to
+        the pass's last version with one wake-up of the waiters."""
+        routes = self._routes
+        by_route: Dict[object, list] = {}
+        by_resource = self._applied_by_resource
+        for version, etype, key, obj, _prev in items:
+            for prefix, cache in routes:
+                if key.startswith(prefix):
+                    by_route.setdefault(cache, []).append(
+                        (version, etype, key, obj)
+                    )
+                    break
+            # key shape '/registry/<resource>/...' — split bounded at 3.
+            parts = key.split("/", 3)
+            if len(parts) > 2:
+                by_resource[parts[2]] = version
+        for cache, events in by_route.items():
+            cache.apply_many(events)
         with self._applied_cond:
-            self.applied = version
+            self.applied = items[-1][0]
             self._applied_cond.notify_all()
 
     def applied_version(self, resource: str) -> int:
@@ -317,9 +329,9 @@ class WatchCacheSet:
 
 class _BufferingRoute:
     """Stand-in route that buffers events while its real cache seeds;
-    drain_into() replays them (idempotent — apply() drops versions the
-    seed already covered) and then forwards directly, preserving the
-    dispatcher's version order."""
+    drain_into() replays them (idempotent — apply_many() drops versions
+    the seed already covered) and then forwards directly, preserving
+    the dispatcher's version order."""
 
     def __init__(self, prefix: str):
         self.prefix = prefix
@@ -327,13 +339,13 @@ class _BufferingRoute:
         self._buf: List[tuple] = []
         self._target: Optional[ResourceCache] = None
 
-    def apply(self, version: int, etype: str, key: str, obj: dict) -> None:
+    def apply_many(self, events) -> None:
         with self._lock:
             if self._target is None:
-                self._buf.append((version, etype, key, obj))
+                self._buf.extend(events)
                 return
             target = self._target
-        target.apply(version, etype, key, obj)
+        target.apply_many(events)
 
     def drain_into(self, cache: ResourceCache) -> None:
         # Replay UNDER the lock: a live event racing in must queue
@@ -341,8 +353,7 @@ class _BufferingRoute:
         # events (a DELETED overtaken by a buffered older ADDED would
         # resurrect the object).
         with self._lock:
-            for version, etype, key, obj in self._buf:
-                cache.apply(version, etype, key, obj)
+            cache.apply_many(self._buf)
             self._buf = []
             self._target = cache
 

@@ -53,7 +53,7 @@ from collections import deque
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from kubernetes_tpu.store.watch import ADDED, DELETED, Event, MODIFIED, WatchStream
-from kubernetes_tpu.utils import faults, sanitizer, tracing
+from kubernetes_tpu.utils import faults, metrics, sanitizer, tracing
 
 
 class StoreError(Exception):
@@ -103,43 +103,107 @@ def _copy_obj(obj: dict) -> dict:
         return copy.deepcopy(obj)
 
 
+def _copy_objs(objs: List[dict]) -> List[dict]:
+    """_copy_obj of each object, in one JSON round-trip (the per-call
+    overhead is about 40% of a pod's copy): a private copy each, even
+    for an object listed twice."""
+    try:
+        return json.loads(json.dumps(objs))
+    except (TypeError, ValueError):
+        return [_copy_obj(o) for o in objs]
+
+
+#: Passes of the watch dispatcher and the events they fanned out: their
+#: ratio is the events per pass, 1 for a lone write and the backlog's
+#: depth under bursts (see _dispatch_thread).
+DISPATCH_PASSES = metrics.DEFAULT.counter(
+    "watch_dispatch_passes_total",
+    "Passes of the store's watch dispatcher (one per drained backlog)",
+)
+DISPATCH_EVENTS = metrics.DEFAULT.counter(
+    "watch_dispatch_events_total",
+    "Store events fanned out by the watch dispatcher",
+)
+
+
 def _dispatch_thread(store_ref: "weakref.ref", q: "_queue.SimpleQueue") -> None:
-    """Drains a store's dispatch queue until a None sentinel (close) or
-    the store itself is collected."""
+    """Drains a store's dispatch queue a backlog at a time: each wake
+    takes everything queued (one blocking get, then non-blocking gets
+    until the queue is empty) and fans it out in one pass
+    (_dispatch_batch). It never waits to fill a batch, so a lone event
+    is a pass of one and a burst is a pass of its backlog's depth. A
+    None sentinel (close) ends the thread after the events ahead of it
+    are delivered; so does the store's collection."""
     while True:
         item = q.get()
         if item is None:
             return
+        batch = [item]
+        stop = False
+        while True:
+            try:
+                item = q.get_nowait()
+            except _queue.Empty:
+                break
+            if item is None:
+                stop = True
+                break
+            batch.append(item)
         store = store_ref()
         if store is None:
             return
-        store._dispatch_event(item)
+        store._dispatch_batch(batch)
         del store  # don't pin the store across the blocking get()
+        if stop:
+            return
+
+
+def _matches(pred: Callable, extract, memo: Optional[dict], o: dict) -> bool:
+    """pred(o); for a selector pred that exposes its field extractor
+    (see KVStore.watch), the fields come from `memo`, so one pass
+    extracts them once per object however many watchers test it."""
+    if extract is None:
+        return pred(o)
+    k = (id(o), extract)
+    fields = memo.get(k)
+    if fields is None:
+        fields = memo[k] = extract(o)
+    return pred.matches(o, fields)
+
+
+def _filter_type(
+    pred: Callable, extract, memo: Optional[dict],
+    etype: str, obj: dict, prev: Optional[dict],
+) -> Optional[str]:
+    """etcd's filtered-watch translation (pkg/tools/etcd_helper_watch.go
+    sendModify/sendDelete): the event type a selector-filtered watcher
+    sees, or None. ADDED/MODIFIED only while the object matches, a
+    synthesized DELETED when a modification takes it out of the filter
+    (so a spec.nodeName="" watcher sees pods leave its view when the
+    scheduler binds them), and nothing at all for objects that never
+    concerned it. With no previous state to consult (history replay), a
+    non-matching MODIFIED degrades to a spurious DELETED — a harmless
+    no-op for consumers."""
+    if etype == ADDED:
+        return ADDED if _matches(pred, extract, memo, obj) else None
+    if etype == MODIFIED:
+        if _matches(pred, extract, memo, obj):
+            return MODIFIED
+        if prev is None or _matches(pred, extract, memo, prev):
+            return DELETED
+        return None
+    # DELETED: obj is the last stored state — deliver iff it was visible.
+    return DELETED if _matches(pred, extract, memo, obj) else None
 
 
 def _filter_event(
     pred: Optional[Callable], etype: str, obj: dict, prev: Optional[dict], version: int
 ) -> Optional[Event]:
-    """etcd's filtered-watch translation (pkg/tools/etcd_helper_watch.go
-    sendModify/sendDelete): a selector-filtered watcher sees ADDED/
-    MODIFIED only while the object matches, a synthesized DELETED when a
-    modification takes it out of the filter (so a spec.nodeName=""
-    watcher sees pods leave its view when the scheduler binds them), and
-    nothing at all for objects that never concerned it. With no previous
-    state to consult (history replay), a non-matching MODIFIED degrades
-    to a spurious DELETED — a harmless no-op for consumers."""
+    """The event a watcher with `pred` sees (_filter_type), or None."""
     if pred is None:
         return Event(etype, obj, version)
-    if etype == ADDED:
-        return Event(ADDED, obj, version) if pred(obj) else None
-    if etype == MODIFIED:
-        if pred(obj):
-            return Event(MODIFIED, obj, version)
-        if prev is None or pred(prev):
-            return Event(DELETED, obj, version)
-        return None
-    # DELETED: obj is the last stored state — deliver iff it was visible.
-    return Event(DELETED, obj, version) if pred(obj) else None
+    etype = _filter_type(pred, None, None, etype, obj, prev)
+    return None if etype is None else Event(etype, obj, version)
 
 
 def _drain_write_queue(q, batch=()) -> None:
@@ -266,16 +330,17 @@ class KVStore:
         # spec.nodeName=X) must not even be offered the other 99 nodes'
         # events — at 100 kubelets that fan-out was the control plane's
         # wall, not the solver.
-        # watcher tuple: (prefix, pred, stream, shard) where shard is
-        # None or (extract_fn, value) — see _dispatch_event.
+        # watcher tuple: (prefix, pred, stream, shard, extract) where
+        # shard is None or (extract_fn, value) and extract is pred's
+        # field extractor or None — see watch() and _dispatch_batch.
         self._watchers: List[tuple] = []
         self._unsharded: List[tuple] = []
         self._shard_buckets: Dict[tuple, List[tuple]] = {}
         self._shard_fns: tuple = ()
-        # Event subscribers (the apiserver's watch cache): called on
-        # the DISPATCHER thread for every event, before watcher
-        # fan-out, with the stored (read-only) object — no copy. See
-        # subscribe().
+        # Event subscribers (the apiserver's watch cache), as (fn, many)
+        # pairs: called on the DISPATCHER thread for every event, before
+        # watcher fan-out, with the stored (read-only) object — no copy.
+        # See subscribe().
         self._subscribers: tuple = ()
         # WAL taps (the replication hub's feed): called UNDER self._lock
         # with (version, raw_line) for every journaled mutation, in
@@ -293,7 +358,8 @@ class KVStore:
         # Fan-out rides its own thread: writers only append to this
         # queue under the lock; the dispatcher does the per-event copy
         # and per-watcher predicate work OFF the write path, so write
-        # latency is independent of watcher count. Ordering: single
+        # latency is independent of watcher count, and it takes the
+        # queue a backlog at a time (_dispatch_thread). Ordering: single
         # dispatcher = version order; replay/live races are settled by
         # each stream's version floor (WatchStream.push).
         self._dispatch_q: "_queue.SimpleQueue" = _queue.SimpleQueue()
@@ -804,15 +870,36 @@ class KVStore:
             (version, etype, key, obj, prev, time.monotonic())
         )
 
-    def _dispatch_event(self, item: tuple) -> None:
-        """Watch fan-out for one event, off the write path. ALL watchers
-        share ONE private copy per event: stored state stays unreachable
-        through events, and the copy cost doesn't scale with watcher
-        count (at 100 kubelets a per-watcher deepcopy under the store
-        lock was the control plane's wall, not the solver). Event
-        objects are read-only by contract — every consumer either
-        JSON-encodes them (HTTP watch) or decodes them into fresh typed
-        objects (serde.from_wire rebuilds every container).
+    def _dispatch_batch(self, items: List[tuple]) -> None:
+        """Watch fan-out for one drained backlog, in version order, off
+        the write path. The work that does not depend on the event is
+        done once per pass: the wait observations, the subscriber
+        calls, the read of the watcher index, the closed-stream checks
+        and one push per stream.
+
+        1. Each event's own time in the queue is observed
+           (`watch.fanout_wait`, one observation per event).
+        2. Subscribers get the whole batch before any watcher sees any
+           of it (subscribe()).
+        3. The watcher index is read once, AFTER the drain: watch()
+           sets a new stream's floor to the store's version under the
+           lock, so every event drained earlier lies at or below the
+           floor of any watcher that registered after this read. A read
+           before the drain could miss a watcher registered in between,
+           and it would lose the events above its floor.
+        4. A selector pred that exposes its field extractor gets each
+           object's fields once per pass, not once per watcher.
+        5. ALL watchers share ONE private copy per event, and a pass
+           makes its copies in one JSON round-trip: stored state stays
+           unreachable through events, and the copy cost doesn't scale
+           with watcher count (at 100 kubelets a per-watcher deepcopy
+           under the store lock was the control plane's wall, not the
+           solver). Event objects are read-only by contract —
+           every consumer either JSON-encodes them (HTTP watch) or
+           decodes them into fresh typed objects (serde.from_wire
+           rebuilds every container).
+        6. Each stream gets its events in one WatchStream.push_many;
+           streams found closed, or dropped by it, are pruned once.
 
         Sharded watchers (watch(..., shard=(fn, value))) are indexed by
         their shard value and only offered events whose object (or
@@ -824,47 +911,98 @@ class KVStore:
         only match (directly or through the DELETED translation) when
         obj or prev carries its shard value, so skipped watchers would
         have produced no event anyway."""
-        version, etype, key, obj, prev, queued = item
-        # How long the event sat in the fan-out queue: once per event,
-        # never per watcher.
-        tracing.observe_wait("watch.fanout_wait", time.monotonic() - queued)
-        for sub in self._subscribers:
+        now = time.monotonic()
+        tracing.observe_waits(
+            "watch.fanout_wait", [now - item[5] for item in items]
+        )
+        DISPATCH_PASSES.inc()
+        DISPATCH_EVENTS.inc(len(items))
+        events = [item[:5] for item in items]
+        with self._lock:
+            subscribers = self._subscribers
+            unsharded = self._unsharded
+            shard_fns = self._shard_fns
+            buckets = self._shard_buckets
+        for fn, many in subscribers:
             # Subscribers see every event in version order before the
             # watcher fan-out (they feed read caches, so they must be
             # at least as fresh as anything a watcher could observe).
-            # obj is the stored object — read-only by contract.
-            try:
-                sub(version, etype, key, obj, prev)
-            except Exception:
-                pass  # a broken cache must not stall watch fan-out
-        with self._lock:
-            watchers = list(self._unsharded)
-            for fn in self._shard_fns:  # distinct extractors (usually 1)
-                vals = {fn(obj)}
-                if prev is not None:
-                    vals.add(fn(prev))
-                for v in vals:
-                    watchers.extend(self._shard_buckets.get((fn, v), ()))
-        delivered = None  # lazily copied: most events match few watchers
-        saw_closed = False
-        for prefix, pred, stream, _shard in watchers:
-            if stream.closed:
-                saw_closed = True
+            # obj is the stored object — read-only by contract. A
+            # broken cache must not stall watch fan-out.
+            if many is not None:
+                try:
+                    many(events)
+                except Exception:
+                    pass
                 continue
-            if key.startswith(prefix):
-                ev = _filter_event(pred, etype, obj, prev, version)
-                if ev is not None:
-                    if delivered is None:
-                        delivered = _copy_obj(obj)
-                    stream.push(Event(ev.type, delivered, version))
-            if stream.closed:
-                saw_closed = True  # push() just dropped a slow consumer
-        if saw_closed:
-            with self._lock:
-                self._watchers = [
-                    w for w in self._watchers if not w[2].closed
-                ]
-                self._rebuild_watch_index_locked()
+            for ev in events:
+                try:
+                    fn(*ev)
+                except Exception:
+                    pass
+        offered = [w for w in unsharded if not w[2].closed]
+        prune = len(offered) != len(unsharded)
+        live_buckets: Dict[tuple, List[tuple]] = {}
+        memo: Dict[tuple, Any] = {}
+        routed = []  # (version, obj, [(stream, type)]) of taken events
+        for version, etype, key, obj, prev in events:
+            watchers = offered
+            if shard_fns:
+                watchers = list(offered)
+                for fn in shard_fns:  # distinct extractors (usually 1)
+                    vals = {fn(obj)}
+                    if prev is not None:
+                        vals.add(fn(prev))
+                    for v in vals:
+                        live = live_buckets.get((fn, v))
+                        if live is None:
+                            bucket = buckets.get((fn, v), ())
+                            live = [w for w in bucket if not w[2].closed]
+                            prune = prune or len(live) != len(bucket)
+                            live_buckets[(fn, v)] = live
+                        watchers.extend(live)
+            takers = None
+            for prefix, pred, stream, _shard, extract in watchers:
+                if not key.startswith(prefix):
+                    continue
+                t = etype
+                if pred is not None:
+                    t = _filter_type(pred, extract, memo, etype, obj, prev)
+                    if t is None:
+                        continue
+                if takers is None:
+                    takers = []
+                    routed.append((version, obj, takers))
+                takers.append((stream, t))
+        if not routed:
+            if prune:
+                self._prune_closed_watchers()
+            return
+        # Copied only if some watcher takes it: most events match few
+        # watchers.
+        copies = _copy_objs([obj for _v, obj, _t in routed])
+        out: Dict[WatchStream, List[Event]] = {}
+        for (version, _obj, takers), delivered in zip(routed, copies):
+            typed: Dict[str, Event] = {}
+            for stream, t in takers:
+                ev = typed.get(t)
+                if ev is None:
+                    ev = typed[t] = Event(t, delivered, version)
+                queued = out.get(stream)
+                if queued is None:
+                    out[stream] = [ev]
+                else:
+                    queued.append(ev)
+        for stream, evs in out.items():
+            if not stream.push_many(evs):
+                prune = True  # closed, or push_many dropped a slow consumer
+        if prune:
+            self._prune_closed_watchers()
+
+    def _prune_closed_watchers(self) -> None:
+        with self._lock:
+            self._watchers = [w for w in self._watchers if not w[2].closed]
+            self._rebuild_watch_index_locked()
 
     def _rebuild_watch_index_locked(self) -> None:
         self._unsharded = []
@@ -1250,16 +1388,24 @@ class KVStore:
 
     # -- Watch --------------------------------------------------------
 
-    def subscribe(self, fn: Callable) -> None:
-        """Register an event subscriber: fn(version, etype, key, obj,
-        prev) is invoked on the dispatcher thread for EVERY event, in
-        version order, before watcher fan-out. `obj` is the stored
-        object itself (read-only by contract — subscribers must not
-        mutate and must copy before handing out). This is the
-        apiserver watch cache's feed: one hook, no extra threads, no
-        per-event copies."""
+    def subscribe(
+        self, fn: Optional[Callable] = None, *, many: Optional[Callable] = None
+    ) -> None:
+        """Register an event subscriber, in one of two forms:
+        fn(version, etype, key, obj, prev) is invoked for EVERY event;
+        many(items) once per dispatcher pass, with the pass's events as
+        a list of (version, etype, key, obj, prev) in version order.
+        Either runs on the dispatcher thread, and every subscriber has
+        had the whole pass before any watcher is offered any of it: a
+        cache fed here is at least as fresh as anything a watcher can
+        observe. `obj` is the stored object itself (read-only by
+        contract — subscribers must not mutate and must copy before
+        handing out). This is the apiserver watch cache's feed: one
+        hook, no extra threads, no per-event copies."""
+        if (fn is None) == (many is None):
+            raise TypeError("subscribe() takes exactly one of fn, many")
         with self._lock:
-            self._subscribers = self._subscribers + (fn,)
+            self._subscribers = self._subscribers + ((fn, many),)
 
     # -- Replication (store/replication.py rides these seams) ---------
 
@@ -1467,6 +1613,12 @@ class KVStore:
         1000-kubelets-each-watching-their-node shape. extract_fn must
         be a shared (module-level) callable so equal shards hash
         together.
+
+        A `pred` may expose its field extractor as `pred.extract` (a
+        shared callable, obj -> fields) with `pred.matches(obj,
+        fields)` answering as pred(obj) does: the dispatcher then
+        extracts each object's fields once per pass for every watcher
+        with that extractor.
         """
         with self._lock:
             self._expire_locked()
@@ -1503,7 +1655,8 @@ class KVStore:
             self._watchers = [
                 w for w in self._watchers if not w[2].closed
             ]
-            self._watchers.append((prefix, pred, stream, shard))
+            extract = getattr(pred, "extract", None)
+            self._watchers.append((prefix, pred, stream, shard, extract))
             self._rebuild_watch_index_locked()
             return stream
 

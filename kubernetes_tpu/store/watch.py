@@ -107,6 +107,46 @@ class WatchStream:
         except queue.Full:
             return self._drop_slow_consumer()
 
+    def push_many(self, events: List[Event]) -> bool:
+        """push() for each event, in order, with one queue lock, one
+        notify and one depth sample: the dispatcher's delivery of a
+        whole pass to this stream. Each event still meets the version
+        floor; what fits goes in, and on overflow the stream is dropped
+        exactly as push() drops it. With faults enabled it is a loop of
+        push(), so the chaos seams fire per event. False once the
+        stream is closed."""
+        if self._closed.is_set():
+            return False
+        if faults.enabled() and self.resource and self.resource != "broadcast":
+            for ev in events:
+                if not self.push(ev):
+                    return False
+            return True
+        floor = self.floor
+        q = self._q
+        overflow = False
+        with q.mutex:
+            items = q.queue
+            before = len(items)
+            room = q.maxsize - before if q.maxsize > 0 else len(events)
+            for ev in events:
+                if ev.version and ev.version <= floor:
+                    continue
+                if room <= 0:
+                    overflow = True
+                    break
+                items.append(ev)
+                room -= 1
+            depth = len(items)
+            if depth > before:
+                q.unfinished_tasks += depth - before
+                q.not_empty.notify(depth - before)
+        if overflow:
+            return self._drop_slow_consumer()
+        if depth >> 6 > before >> 6:  # crossed a multiple of 64, as push()
+            QUEUE_DEPTH.set(depth, resource=self.resource)
+        return True
+
     def _drop_slow_consumer(self) -> bool:
         # Slow consumer: drop the stream (reference watchers are also
         # terminated and must re-list; Reflector handles that) —

@@ -41,6 +41,8 @@ class _Metric:
         self._lock = sanitizer.lock("metrics.series")
 
     def _key(self, labels: Dict[str, str]) -> Tuple[str, ...]:
+        if not self.label_names:
+            return ()
         return tuple(labels.get(k, "") for k in self.label_names)
 
     def _header(self, type_: str) -> List[str]:
@@ -254,6 +256,32 @@ class Histogram(_Metric):
                     break
             # value > highest bound: only the implicit +Inf bucket
             # (== count) observes it.
+
+    def observe_many(self, values: Sequence[float], **labels) -> None:
+        """observe() for each value, with one label lookup and one lock
+        hold: the same buckets, sum and count."""
+        if not values:
+            return
+        bounds = self.buckets
+        with self._lock:
+            k = self._key(labels)
+            s = self._stats.get(k)
+            if s is None:
+                s = self._stats[k] = {
+                    "count": 0,
+                    "sum": 0.0,
+                    "buckets": [0] * len(bounds),
+                }
+            counts = s["buckets"]
+            total = s["sum"]
+            for value in values:
+                total += value
+                for i, ub in enumerate(bounds):
+                    if value <= ub:
+                        counts[i] += 1
+                        break
+            s["sum"] = total
+            s["count"] += len(values)
 
     def count(self, **labels) -> int:
         with self._lock:

@@ -211,8 +211,8 @@ class LifecycleSLICollector:
     state is kept current by events alone — it never lists or polls).
 
     Feed it by attaching to a store (``attach``: the kvstore dispatcher
-    invokes ``_on_store_event`` for every event, in version order, on
-    its own thread) and by the flight-recorder decision sink registered
+    invokes ``_on_store_events`` once a pass with its events, in
+    version order, on its own thread) and by the flight-recorder decision sink registered
     at module import (``note_decision``). Thread-safe; observations
     happen outside the track lock."""
 
@@ -233,65 +233,62 @@ class LifecycleSLICollector:
         """Subscribe to a kvstore's event dispatcher (the same feed the
         apiserver watch cache rides). Idempotent per store: KVStore
         subscribers are append-only, so attach once per store."""
-        store.subscribe(self._on_store_event)
+        store.subscribe(many=self._on_store_events)
 
     # -- event feed (dispatcher thread) --------------------------------
 
     def _on_store_event(self, version, etype, key, obj, prev) -> None:
-        # Hot path: runs on the store dispatcher thread for EVERY pod
-        # event — locals bound, untracked pods bail before any parsing,
-        # the lock is taken only when state actually changes (dict
-        # reads are GIL-atomic; the dispatcher is the sole writer of
-        # store-event transitions).
-        if not self.enabled or not key.startswith(POD_PREFIX):
+        self._on_store_events(((version, etype, key, obj, prev),))
+
+    def _on_store_events(self, items) -> None:
+        """One dispatcher pass, in version order: one clock read, one
+        lock hold for the pass's pod events, one histogram call per
+        milestone. Every event of a pass is seen at the same instant."""
+        if not self.enabled:
             return
-        pod_key = key[_PREFIX_LEN:]
-        tracks = self._tracks
-        if etype == "DELETED":
-            if pod_key in tracks:
-                with self._lock:
-                    tracks.pop(pod_key, None)
-            return
-        if not isinstance(obj, dict):
-            return
-        spec = obj.get("spec")
-        if etype == "ADDED":
-            if spec and spec.get("nodeName"):
-                return  # born bound (static pod / replay): no startup story
-            now = time.monotonic()
-            with self._lock:
-                if (
-                    len(tracks) >= self.MAX_TRACKED
-                    and pod_key not in tracks
-                ):
-                    tracks.pop(next(iter(tracks)))
-                tracks[pod_key] = [now, False, False, False]
-            return
-        # MODIFIED: bound / running transitions (tracked pods only).
-        if pod_key not in tracks:
-            return
-        bound = bool(spec and spec.get("nodeName"))
-        status = obj.get("status")
-        running = bool(status) and status.get("phase") == "Running"
-        if not (bound or running):
+        pods = [it for it in items if it[2].startswith(POD_PREFIX)]
+        if not pods:
             return
         now = time.monotonic()
-        observe_bound = observe_running = False
+        bound_waits: List[float] = []
+        running_waits: List[float] = []
+        tracks = self._tracks
         with self._lock:
-            t = tracks.get(pod_key)
-            if t is None:
-                return
-            created = t[0]
-            if bound and not t[2]:
-                t[2] = observe_bound = True
-            if running and not t[3]:
-                t[3] = observe_running = True
-            if t[3]:
-                del tracks[pod_key]  # lifecycle complete: drain
-        if observe_bound:
-            STARTUP_LATENCY.observe(now - created, milestone="bound")
-        if observe_running:
-            STARTUP_LATENCY.observe(now - created, milestone="running")
+            for _version, etype, key, obj, _prev in pods:
+                pod_key = key[_PREFIX_LEN:]
+                if etype == "DELETED":
+                    tracks.pop(pod_key, None)
+                    continue
+                if not isinstance(obj, dict):
+                    continue
+                spec = obj.get("spec")
+                if etype == "ADDED":
+                    if spec and spec.get("nodeName"):
+                        continue  # born bound (static pod / replay): no startup story
+                    if (
+                        len(tracks) >= self.MAX_TRACKED
+                        and pod_key not in tracks
+                    ):
+                        tracks.pop(next(iter(tracks)))
+                    tracks[pod_key] = [now, False, False, False]
+                    continue
+                # MODIFIED: bound / running transitions (tracked pods only).
+                t = tracks.get(pod_key)
+                if t is None:
+                    continue
+                status = obj.get("status")
+                if spec and spec.get("nodeName") and not t[2]:
+                    t[2] = True
+                    bound_waits.append(now - t[0])
+                if status and status.get("phase") == "Running" and not t[3]:
+                    t[3] = True
+                    running_waits.append(now - t[0])
+                if t[3]:
+                    del tracks[pod_key]  # lifecycle complete: drain
+        if bound_waits:
+            STARTUP_LATENCY.observe_many(bound_waits, milestone="bound")
+        if running_waits:
+            STARTUP_LATENCY.observe_many(running_waits, milestone="running")
 
     # -- decision join (flight-recorder sink, scheduler thread) --------
 

@@ -44,7 +44,7 @@ import random
 import sys
 import threading
 import time
-from typing import Dict, Iterable, List, Optional
+from typing import Dict, Iterable, List, Optional, Sequence
 
 from kubernetes_tpu.utils import metrics
 
@@ -460,6 +460,11 @@ def observe_wait(name: str, seconds: float) -> None:
     queue — observed into scheduler_phase_seconds under ``name``. No
     span and no annotation: the waiting happened on no one thread."""
     PHASE_SECONDS.observe(seconds, phase=name)
+
+
+def observe_waits(name: str, seconds: Sequence[float]) -> None:
+    """observe_wait() for each of many waits, one observation each."""
+    PHASE_SECONDS.observe_many(seconds, phase=name)
 
 
 # -- rendering (shared by the LogIfLong dump and `ktctl trace`) --------

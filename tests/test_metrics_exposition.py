@@ -136,6 +136,25 @@ class TestHistogram:
 
         assert math.isnan(metrics.Histogram("q3_seconds", "x").quantile(0.5))
 
+    def test_observe_many_equals_repeated_observe(self):
+        import random
+
+        rng = random.Random(11)
+        values = [rng.expovariate(0.5) for _ in range(400)] + [
+            0.0, 0.005, 120.0, 121.0,
+        ]
+        one = metrics.Histogram("one_seconds", "x", ("phase",))
+        many = metrics.Histogram("one_seconds", "x", ("phase",))
+        for v in values:
+            one.observe(v, phase="p")
+        many.observe_many(values[:100], phase="p")
+        many.observe_many(values[100:], phase="p")
+        many.observe_many([], phase="empty")
+        (k, (n, s, b)), = one.snapshot().items()
+        assert many.snapshot() == {k: (n, s, b)}
+        assert n == len(values)
+        assert many.render() == one.render()
+
     def test_registry_histogram_in_default_render(self):
         h = metrics.DEFAULT.histogram(
             "exposition_test_seconds", "temp", ("k",)
@@ -424,6 +443,41 @@ def test_sli_metrics_exposed():
     )
     assert "# TYPE solver_device_transfer_bytes_total counter" in text
     assert "# TYPE solver_xla_compile_cache_entries gauge" in text
+
+
+def test_watch_dispatch_counters_exposed(tmp_path):
+    """The watch dispatcher's pass and event counters (store/kvstore.py)
+    render on metrics.DEFAULT as counters, and their names pass KT005
+    on their _total suffix alone."""
+    import time
+
+    from kubernetes_tpu.store import kvstore
+
+    store = kvstore.KVStore()
+    try:
+        p0 = kvstore.DISPATCH_PASSES.value()
+        store.create("/registry/pods/default/x", {"metadata": {"name": "x"}})
+        deadline = time.monotonic() + 5
+        while kvstore.DISPATCH_PASSES.value() == p0:
+            assert time.monotonic() < deadline
+            time.sleep(0.01)
+    finally:
+        store.close()
+    text = metrics.DEFAULT.render()
+    for name in ("watch_dispatch_passes_total", "watch_dispatch_events_total"):
+        assert f"# TYPE {name} counter" in text
+        line = next(ln for ln in text.splitlines() if ln.startswith(name + " "))
+        assert float(line.split()[1]) >= 1.0
+    root = pathlib.Path(__file__).resolve().parent.parent
+    good = tmp_path / "good"
+    good.mkdir()
+    (good / "g.py").write_text(
+        "from kubernetes_tpu.utils import metrics\n"
+        'A = metrics.DEFAULT.counter("watch_dispatch_passes_total", "x")\n'
+        'B = metrics.DEFAULT.counter("watch_dispatch_events_total", "x")\n'
+    )
+    proc = _ktlint_kt005(root, good)
+    assert proc.returncode == 0, proc.stderr
 
 
 def test_lint_metrics_knows_profiler_names(tmp_path):

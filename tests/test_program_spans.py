@@ -134,6 +134,17 @@ def test_each_store_write_observes_one_fanout_wait():
         assert wait_for(lambda: count("watch.fanout_wait") >= before + n)
         time.sleep(0.05)
         assert count("watch.fanout_wait") == before + n
+        # A bulk create is one write call but 500 events: 500 waits,
+        # however few dispatcher passes fan them out.
+        before = count("watch.fanout_wait")
+        bulk = 500
+        store.create_many([
+            (f"/registry/pods/default/b{i}", {"metadata": {"name": f"b{i}"}}, None)
+            for i in range(bulk)
+        ])
+        assert wait_for(lambda: count("watch.fanout_wait") >= before + bulk)
+        time.sleep(0.05)
+        assert count("watch.fanout_wait") == before + bulk
     finally:
         store.close()
 
