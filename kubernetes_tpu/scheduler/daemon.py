@@ -19,7 +19,9 @@ import threading
 import time
 from typing import Callable, Dict, List, Optional, Tuple
 
-from kubernetes_tpu.client.cache import FIFO, Informer, Reflector, ThreadSafeStore
+from kubernetes_tpu.client.cache import (
+    FIFO, Informer, Reflector, ThreadSafeStore, meta_namespace_key,
+)
 from kubernetes_tpu.models import serde
 from kubernetes_tpu.models.objects import Node, Pod, Service
 from kubernetes_tpu.scheduler.generic import FitError, GenericScheduler, NoNodesError
@@ -36,6 +38,7 @@ from kubernetes_tpu.scheduler.plugins import (
 _LOG = logging.getLogger("kubernetes_tpu.scheduler")
 from kubernetes_tpu.scheduler.types import StaticNodeLister, StaticServiceLister
 from kubernetes_tpu.server.api import APIError
+from kubernetes_tpu.store.watch import ADDED, DELETED, MODIFIED
 from kubernetes_tpu.utils import (
     faults,
     flightrecorder,
@@ -138,6 +141,100 @@ class _StoreNodeLister:
         return node
 
 
+def _bound(obj) -> bool:
+    if isinstance(obj, dict):
+        return bool((obj.get("spec") or {}).get("nodeName"))
+    return bool(obj.spec.node_name)
+
+
+def _typed_pod(obj) -> Pod:
+    return _decode_pod(obj) if isinstance(obj, dict) else obj
+
+
+class _PodSplit:
+    """The store behind the scheduler's one pod watch (its Reflector's
+    add/update/delete/replace, in version order; watch events are wire
+    dicts, a typed client's relist gives Pods). Each pod goes to the
+    pending FIFO or the scheduled-pods cache by spec.nodeName, with the
+    events each side's own field-selected watch would have had: a bind
+    leaves the FIFO and reaches the cache as MODIFIED, as etcd's
+    out-of-filter translation gives the two sides. `emit(etype, obj)`
+    receives the scheduled side's deltas.
+
+    A relist (after a watch the store could no longer resume) emits
+    DELETED for every pod it no longer lists — the scheduled ones, and
+    the pending ones the split has seen, whose deletes the relist
+    skipped too: the scheduler may have placed such a pod and charged
+    it before its bind came back through the watch — then ADDED for
+    each scheduled pod (as Reflector._list does for an informer)."""
+
+    key_func = staticmethod(meta_namespace_key)
+
+    def __init__(self, queue: FIFO, scheduled: ThreadSafeStore, decode_scheduled, emit):
+        self.queue = queue
+        self.scheduled = scheduled
+        self._decode = decode_scheduled  # None: keep the wire form
+        self._emit = emit
+        # Unbound pods seen and neither bound nor deleted since, by key
+        # (only the reflector's thread touches it).
+        self._pending: Dict[str, object] = {}
+
+    def _scheduled_form(self, obj):
+        if self._decode is not None and isinstance(obj, dict):
+            return self._decode(obj)
+        return obj
+
+    def add(self, obj) -> None:
+        self._put(ADDED, obj)
+
+    def update(self, obj) -> None:
+        self._put(MODIFIED, obj)
+
+    def _put(self, etype: str, obj) -> None:
+        key = self.key_func(obj)
+        if _bound(obj):
+            self._pending.pop(key, None)
+            self.queue.delete(obj)
+            obj = self._scheduled_form(obj)
+            self.scheduled.add(obj)
+            self._emit(etype, obj)
+            return
+        if self.scheduled.get(key) is not None:
+            self.scheduled.delete(obj)
+            self._emit(DELETED, obj)
+        self._pending[key] = obj
+        self.queue.add(_typed_pod(obj))
+
+    def delete(self, obj) -> None:
+        if _bound(obj):
+            self.scheduled.delete(obj)
+            self._emit(DELETED, obj)
+        else:
+            self._pending.pop(self.key_func(obj), None)
+            self.queue.delete(obj)
+
+    def replace(self, objs: List) -> None:
+        bound = [self._scheduled_form(o) for o in objs if _bound(o)]
+        pending = [o for o in objs if not _bound(o)]
+        self.queue.replace([_typed_pod(o) for o in pending])
+        listed = {self.key_func(o) for o in objs}
+        vanished = [
+            o for k, o in self._pending.items() if k not in listed
+        ] + [
+            o for o in (
+                self.scheduled.get(k) for k in self.scheduled.keys()
+                if k not in listed
+            )
+            if o is not None
+        ]
+        self._pending = {self.key_func(o): o for o in pending}
+        self.scheduled.replace(bound)
+        for o in vanished:
+            self._emit(DELETED, o)
+        for o in bound:
+            self._emit(ADDED, o)
+
+
 class SchedulerConfig:
     """Wires caches + algorithm (reference: factory.CreateFromKeys)."""
 
@@ -158,19 +255,18 @@ class SchedulerConfig:
         # was the reflector threads' main cost under 1k/s churn. Typed
         # consumers (scalar fallback, session rebuild) decode on access.
         self.raw_scheduled_cache = raw_scheduled_cache
-        # Unassigned pods -> FIFO (factory.go:180-186, field selector
-        # "spec.nodeName="). DELETED events (pod bound or removed) only
-        # need the key to drop the FIFO entry — skip their decode.
+        # One watch of every pod, split by binding (kube-scheduler's
+        # shared pod informer with its assigned/unassigned filters):
+        # unassigned pods -> FIFO (factory.go:180-186), assigned pods ->
+        # the scheduled-pods cache (for occupancy), whose deltas also
+        # feed cluster_events. One stream is one version order, so no
+        # pod reaches the FIFO before every bind and delete committed
+        # ahead of it has reached the cache; two filtered watches drift
+        # apart whenever one trails the other (a slow-consumer drop and
+        # its resume, say), and ticks then solve on a stale cluster.
         # Each pop observes the pod's wait since it was queued.
         self.pod_queue = FIFO(wait_phase="sched.queue_wait")
-        self._pod_reflector = Reflector(
-            client,
-            "pods",
-            self.pod_queue,
-            field_selector="spec.nodeName=",
-            decode=_decode_pod,
-            decode_deleted=False,
-        )
+        self.scheduled_pods = ThreadSafeStore()
 
         # Cluster-event hook: the incremental batch scheduler subscribes
         # to watch DELTAS (not just cache state) to keep its device-
@@ -186,13 +282,21 @@ class SchedulerConfig:
 
             return handler
 
-        # Scheduled pods cache (for occupancy).
-        self.scheduled_pods = Informer(
-            client, "pods", field_selector="spec.nodeName!=",
-            decode=None if raw_scheduled_cache else _decode_pod,
-            on_add=_emit("pod", "ADDED"),
-            on_update=_emit("pod", "MODIFIED"),
-            on_delete=_emit("pod", "DELETED"),
+        def _emit_pod(etype: str, obj) -> None:
+            cb = self.cluster_events
+            if cb is not None:
+                cb("pod", etype, obj)
+
+        # DELETED events only need the key to drop an entry — skip
+        # their decode.
+        self._pod_reflector = Reflector(
+            client,
+            "pods",
+            _PodSplit(
+                self.pod_queue, self.scheduled_pods,
+                decode_scheduled=None if raw_scheduled_cache else _decode_pod,
+                emit=_emit_pod,
+            ),
             decode_deleted=False,
         )
         # Nodes + services caches (factory.go:187-193).
@@ -222,7 +326,7 @@ class SchedulerConfig:
             # (rare) access points — scalar fallback, session rebuild.
             return [
                 _decode_pod(p) if isinstance(p, dict) else p
-                for p in self.scheduled_pods.store.list()
+                for p in self.scheduled_pods.list()
             ]
 
         self.modeler = SimpleModeler(
@@ -262,7 +366,6 @@ class SchedulerConfig:
 
     def start(self) -> "SchedulerConfig":
         self._pod_reflector.start()
-        self.scheduled_pods.start()
         self.nodes.start()
         self.services.start()
         self.podgroups.start()
@@ -272,16 +375,15 @@ class SchedulerConfig:
         return all(
             x.wait_for_sync(timeout)
             for x in (
-                self._pod_reflector, self.scheduled_pods, self.nodes,
-                self.services, self.podgroups,
+                self._pod_reflector, self.nodes, self.services,
+                self.podgroups,
             )
         )
 
     def stop(self) -> None:
         self.pod_queue.close()
         for x in (
-            self._pod_reflector, self.scheduled_pods, self.nodes,
-            self.services, self.podgroups,
+            self._pod_reflector, self.nodes, self.services, self.podgroups,
         ):
             x.stop()
 
@@ -948,8 +1050,7 @@ class BatchScheduler(Scheduler):
         cfg = self.config
         now = time.monotonic()
         for resource, ref in (
-            ("pods_pending", cfg._pod_reflector),
-            ("pods_scheduled", cfg.scheduled_pods.reflector),
+            ("pods", cfg._pod_reflector),
             ("nodes", cfg.nodes.reflector),
             ("services", cfg.services.reflector),
             ("podgroups", cfg.podgroups.reflector),

@@ -48,7 +48,7 @@ from kubernetes_tpu.store import (
     KVStore,
     NotFoundError,
 )
-from kubernetes_tpu.store.watch import WatchStream
+from kubernetes_tpu.store.watch import DEFAULT_MAXSIZE, WatchStream
 
 _LOG = logging.getLogger("kubernetes_tpu.apiserver")
 
@@ -103,35 +103,6 @@ def _watch_shard(resource: str, field_selector: str):
             if fn is not None:
                 return (fn, value)
     return None
-
-
-#: The default and the largest depth of a watch's event queue (see
-#: APIServer.watch).
-WATCH_QUEUE_MAX = 65536
-
-
-class _SelectorPred:
-    """A label and field selector as an object predicate. It exposes
-    its field extractor (`extract`, shared per resource) and
-    `matches(obj, fields)`, so the store's watch fan-out extracts an
-    object's fields once for every watcher that tests it
-    (KVStore.watch)."""
-
-    __slots__ = ("lsel", "fsel", "extract")
-
-    def __init__(self, lsel, fsel, extract):
-        self.lsel = lsel
-        self.fsel = fsel
-        self.extract = None if fsel.empty() else extract
-
-    def __call__(self, o: dict) -> bool:
-        return self.matches(o, None if self.extract is None else self.extract(o))
-
-    def matches(self, o: dict, fields) -> bool:
-        if not self.lsel.empty():
-            if not self.lsel.matches(o.get("metadata", {}).get("labels", {})):
-                return False
-        return fields is None or self.fsel.matches(fields)
 
 
 def _not_found(resource: str, name: str) -> APIError:
@@ -894,11 +865,34 @@ class APIServer:
         return cache.get_encoded(info.key(self._ns(info, namespace), name))
 
     def _selector_pred(self, resource: str, label_selector: str, field_selector: str):
+        """The selectors as one predicate, pred(obj)."""
+        pred, fields = self._selector(resource, label_selector, field_selector)
+        if fields is None:
+            return pred
+        return lambda o: pred(o, fields(o))
+
+    @staticmethod
+    def _selector(resource: str, label_selector: str, field_selector: str):
+        """The selectors as (pred, fields), the form KVStore.watch takes:
+        with no field selector, pred(obj) and fields None; else
+        pred(obj, fields(obj)) with the resource's shared field
+        extractor, so the store's fan-out extracts an object's fields
+        once for every watcher that tests them."""
         lsel = labelpkg.parse(label_selector)
         fsel = labelpkg.parse_fields(field_selector)
-        if lsel.empty() and fsel.empty():
-            return lambda o: True
-        return _SelectorPred(lsel, fsel, field_extractor(resource))
+
+        def labels_match(o: dict) -> bool:
+            return lsel.empty() or lsel.matches(
+                o.get("metadata", {}).get("labels", {})
+            )
+
+        if fsel.empty():
+            return labels_match, None
+
+        def pred(o: dict, fields) -> bool:
+            return labels_match(o) and fsel.matches(fields)
+
+        return pred, field_extractor(resource)
 
     def update(self, resource: str, namespace: str, name: str, obj: dict) -> dict:
         info = self._info(resource)
@@ -1410,32 +1404,28 @@ class APIServer:
         since: int = 0,
         label_selector: str = "",
         field_selector: str = "",
-        maxsize: int = WATCH_QUEUE_MAX,
+        maxsize: int = DEFAULT_MAXSIZE,
     ) -> WatchStream:
         """Selector filtering happens INSIDE the store's fan-out (with
         etcd's modified-out-of-filter -> DELETED translation,
-        kvstore._filter_event): a kubelet watching spec.nodeName=X never
+        kvstore._filter_type): a kubelet watching spec.nodeName=X never
         has the other nodes' pod events copied or queued for it.
 
         `maxsize` bounds the consumer's event queue (slow consumers are
-        dropped at overflow and must re-list), clamped to [1024,
-        WATCH_QUEUE_MAX]. The default is the largest: the store's
-        dispatcher hands a stream a whole backlog per pass (a bulk
-        delete of 10,000 pods is 10,000 events at once), and a reader
-        that shares its interpreter with the apiserver takes them at its
-        own pace; the queue is its room to catch up in. A stream dropped
-        mid-burst resumes or re-lists, and meanwhile its client's other
-        watches run ahead of it."""
+        dropped at overflow and must re-list); bulk-churn clients ask
+        for deeper buffers (?maxsize=) so a single group commit's burst
+        of N events cannot out-run one scheduling quantum of their
+        reader."""
         info = self._info(resource)
-        pred = None
+        pred = fields = None
         shard = None
         if label_selector or field_selector:
-            pred = self._selector_pred(resource, label_selector, field_selector)
+            pred, fields = self._selector(resource, label_selector, field_selector)
             shard = _watch_shard(resource, field_selector)
         try:
             return self.store.watch(
                 info.prefix(namespace), since=since, pred=pred, shard=shard,
-                maxsize=max(1024, min(int(maxsize), WATCH_QUEUE_MAX)),
+                fields=fields, maxsize=max(1024, min(int(maxsize), 65536)),
             )
         except Exception as e:  # CompactedError -> 410 Gone
             raise APIError(410, "Expired", str(e))

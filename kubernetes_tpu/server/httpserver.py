@@ -30,8 +30,9 @@ from urllib.parse import parse_qs, urlparse
 
 from kubernetes_tpu import __version__
 from kubernetes_tpu.models import conversion
-from kubernetes_tpu.server.api import WATCH_QUEUE_MAX, APIError, APIServer
+from kubernetes_tpu.server.api import APIError, APIServer
 from kubernetes_tpu.server.registry import RESOURCES
+from kubernetes_tpu.store.watch import DEFAULT_MAXSIZE
 from kubernetes_tpu.utils import metrics, sli, tracing
 
 _REQS = metrics.DEFAULT.counter(
@@ -1553,7 +1554,7 @@ class _Handler(BaseHTTPRequestHandler):
         try:
             since = int(q.get("resourceVersion", "0") or "0")
             timeout = float(q.get("timeoutSeconds", "0") or "0") or None
-            maxsize = int(q.get("maxsize") or WATCH_QUEUE_MAX)
+            maxsize = int(q.get("maxsize") or DEFAULT_MAXSIZE)
         except ValueError:
             raise APIError(
                 400, "BadRequest",

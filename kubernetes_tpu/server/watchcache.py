@@ -239,7 +239,7 @@ class WatchCacheSet:
         # with every pod write's version (false SLO warns). Plain dict:
         # single writer (the dispatcher), GIL-atomic reads.
         self._applied_by_resource: Dict[str, int] = {}
-        store.subscribe(many=self._on_events)
+        store.subscribe(self._on_events)
 
     def _on_events(self, items) -> None:
         """One dispatcher pass, in version order: each resource cache

@@ -52,7 +52,9 @@ import weakref
 from collections import deque
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
-from kubernetes_tpu.store.watch import ADDED, DELETED, Event, MODIFIED, WatchStream
+from kubernetes_tpu.store.watch import (
+    ADDED, DEFAULT_MAXSIZE, DELETED, Event, MODIFIED, WatchStream,
+)
 from kubernetes_tpu.utils import faults, metrics, sanitizer, tracing
 
 
@@ -103,14 +105,24 @@ def _copy_obj(obj: dict) -> dict:
         return copy.deepcopy(obj)
 
 
+#: Objects per JSON round-trip of _copy_objs: enough to spread the
+#: per-call overhead, few enough that one round-trip (which holds the
+#: GIL throughout) stays near a few milliseconds.
+_COPY_CHUNK = 256
+
+
 def _copy_objs(objs: List[dict]) -> List[dict]:
-    """_copy_obj of each object, in one JSON round-trip (the per-call
-    overhead is about 40% of a pod's copy): a private copy each, even
-    for an object listed twice."""
-    try:
-        return json.loads(json.dumps(objs))
-    except (TypeError, ValueError):
-        return [_copy_obj(o) for o in objs]
+    """_copy_obj of each object, _COPY_CHUNK objects per JSON
+    round-trip (the per-call overhead is about 40% of a pod's copy): a
+    private copy each, even for an object listed twice."""
+    out: List[dict] = []
+    for i in range(0, len(objs), _COPY_CHUNK):
+        chunk = objs[i:i + _COPY_CHUNK]
+        try:
+            out.extend(json.loads(json.dumps(chunk)))
+        except (TypeError, ValueError):
+            out.extend(_copy_obj(o) for o in chunk)
+    return out
 
 
 #: Passes of the watch dispatcher and the events they fanned out: their
@@ -158,52 +170,48 @@ def _dispatch_thread(store_ref: "weakref.ref", q: "_queue.SimpleQueue") -> None:
             return
 
 
-def _matches(pred: Callable, extract, memo: Optional[dict], o: dict) -> bool:
-    """pred(o); for a selector pred that exposes its field extractor
-    (see KVStore.watch), the fields come from `memo`, so one pass
-    extracts them once per object however many watchers test it."""
-    if extract is None:
-        return pred(o)
-    k = (id(o), extract)
-    fields = memo.get(k)
+def _matches(pred: Callable, fields, memo: Optional[dict], o: dict) -> bool:
+    """pred(o), or pred(o, fields(o)) for a watcher with a field
+    extractor (KVStore.watch's `fields`). With a `memo` the fields come
+    from it, so one pass extracts them once per object however many
+    watchers test it."""
     if fields is None:
-        fields = memo[k] = extract(o)
-    return pred.matches(o, fields)
+        return pred(o)
+    if memo is None:
+        return pred(o, fields(o))
+    k = (id(o), fields)
+    f = memo.get(k)
+    if f is None:
+        f = memo[k] = fields(o)
+    return pred(o, f)
 
 
 def _filter_type(
-    pred: Callable, extract, memo: Optional[dict],
+    pred: Callable, fields, memo: Optional[dict],
     etype: str, obj: dict, prev: Optional[dict],
 ) -> Optional[str]:
     """etcd's filtered-watch translation (pkg/tools/etcd_helper_watch.go
-    sendModify/sendDelete): the event type a selector-filtered watcher
-    sees, or None. ADDED/MODIFIED only while the object matches, a
+    sendModify/sendDelete): the event type a watcher with `pred` sees,
+    or None (with no pred, every event as it is). ADDED/MODIFIED only
+    while the object matches, a
     synthesized DELETED when a modification takes it out of the filter
     (so a spec.nodeName="" watcher sees pods leave its view when the
     scheduler binds them), and nothing at all for objects that never
     concerned it. With no previous state to consult (history replay), a
     non-matching MODIFIED degrades to a spurious DELETED — a harmless
     no-op for consumers."""
+    if pred is None:
+        return etype
     if etype == ADDED:
-        return ADDED if _matches(pred, extract, memo, obj) else None
+        return ADDED if _matches(pred, fields, memo, obj) else None
     if etype == MODIFIED:
-        if _matches(pred, extract, memo, obj):
+        if _matches(pred, fields, memo, obj):
             return MODIFIED
-        if prev is None or _matches(pred, extract, memo, prev):
+        if prev is None or _matches(pred, fields, memo, prev):
             return DELETED
         return None
     # DELETED: obj is the last stored state — deliver iff it was visible.
-    return DELETED if _matches(pred, extract, memo, obj) else None
-
-
-def _filter_event(
-    pred: Optional[Callable], etype: str, obj: dict, prev: Optional[dict], version: int
-) -> Optional[Event]:
-    """The event a watcher with `pred` sees (_filter_type), or None."""
-    if pred is None:
-        return Event(etype, obj, version)
-    etype = _filter_type(pred, None, None, etype, obj, prev)
-    return None if etype is None else Event(etype, obj, version)
+    return DELETED if _matches(pred, fields, memo, obj) else None
 
 
 def _drain_write_queue(q, batch=()) -> None:
@@ -330,17 +338,17 @@ class KVStore:
         # spec.nodeName=X) must not even be offered the other 99 nodes'
         # events — at 100 kubelets that fan-out was the control plane's
         # wall, not the solver.
-        # watcher tuple: (prefix, pred, stream, shard, extract) where
-        # shard is None or (extract_fn, value) and extract is pred's
+        # watcher tuple: (prefix, pred, stream, shard, fields) where
+        # shard is None or (extract_fn, value) and fields is pred's
         # field extractor or None — see watch() and _dispatch_batch.
         self._watchers: List[tuple] = []
         self._unsharded: List[tuple] = []
         self._shard_buckets: Dict[tuple, List[tuple]] = {}
         self._shard_fns: tuple = ()
-        # Event subscribers (the apiserver's watch cache), as (fn, many)
-        # pairs: called on the DISPATCHER thread for every event, before
-        # watcher fan-out, with the stored (read-only) object — no copy.
-        # See subscribe().
+        # Event subscribers (the apiserver's watch cache): called on
+        # the DISPATCHER thread with every pass's events, before
+        # watcher fan-out, with the stored (read-only) objects — no
+        # copy. See subscribe().
         self._subscribers: tuple = ()
         # WAL taps (the replication hub's feed): called UNDER self._lock
         # with (version, raw_line) for every journaled mutation, in
@@ -873,33 +881,39 @@ class KVStore:
     def _dispatch_batch(self, items: List[tuple]) -> None:
         """Watch fan-out for one drained backlog, in version order, off
         the write path. The work that does not depend on the event is
-        done once per pass: the wait observations, the subscriber
-        calls, the read of the watcher index, the closed-stream checks
-        and one push per stream.
+        done once per pass: the subscriber calls, the read of the
+        watcher index, the closed-stream checks, one push per stream
+        and the wait observations.
 
-        1. Each event's own time in the queue is observed
-           (`watch.fanout_wait`, one observation per event).
-        2. Subscribers get the whole batch before any watcher sees any
+        1. Subscribers get the whole batch before any watcher sees any
            of it (subscribe()).
-        3. The watcher index is read once, AFTER the drain: watch()
+        2. The watcher index is read once, AFTER the drain: watch()
            sets a new stream's floor to the store's version under the
            lock, so every event drained earlier lies at or below the
            floor of any watcher that registered after this read. A read
            before the drain could miss a watcher registered in between,
            and it would lose the events above its floor.
-        4. A selector pred that exposes its field extractor gets each
-           object's fields once per pass, not once per watcher.
-        5. ALL watchers share ONE private copy per event, and a pass
-           makes its copies in one JSON round-trip: stored state stays
+        3. Watchers that share a field extractor (watch()'s `fields`)
+           get each object's fields once per pass, not once per watcher.
+        4. ALL watchers share ONE private copy per event, made
+           _COPY_CHUNK objects per JSON round-trip: stored state stays
            unreachable through events, and the copy cost doesn't scale
            with watcher count (at 100 kubelets a per-watcher deepcopy
            under the store lock was the control plane's wall, not the
            solver). Event objects are read-only by contract —
            every consumer either JSON-encodes them (HTTP watch) or
            decodes them into fresh typed objects (serde.from_wire
-           rebuilds every container).
-        6. Each stream gets its events in one WatchStream.push_many;
+           rebuilds every container). A DELETED event's copy carries
+           the delete's version as its resourceVersion, as etcd's
+           watch gives it: a client resumes from the version of the
+           last object it saw (over HTTP the frame carries no other),
+           and the deleted object's own last write would send it back
+           to events it already had.
+        5. Each stream gets its events in one WatchStream.push_many;
            streams found closed, or dropped by it, are pruned once.
+        6. Each event's time from its commit to its delivery, the end
+           of the pass, is observed (`watch.fanout_wait`, one
+           observation per event).
 
         Sharded watchers (watch(..., shard=(fn, value))) are indexed by
         their shard value and only offered events whose object (or
@@ -911,10 +925,6 @@ class KVStore:
         only match (directly or through the DELETED translation) when
         obj or prev carries its shard value, so skipped watchers would
         have produced no event anyway."""
-        now = time.monotonic()
-        tracing.observe_waits(
-            "watch.fanout_wait", [now - item[5] for item in items]
-        )
         DISPATCH_PASSES.inc()
         DISPATCH_EVENTS.inc(len(items))
         events = [item[:5] for item in items]
@@ -923,28 +933,21 @@ class KVStore:
             unsharded = self._unsharded
             shard_fns = self._shard_fns
             buckets = self._shard_buckets
-        for fn, many in subscribers:
+        for fn in subscribers:
             # Subscribers see every event in version order before the
             # watcher fan-out (they feed read caches, so they must be
             # at least as fresh as anything a watcher could observe).
             # obj is the stored object — read-only by contract. A
             # broken cache must not stall watch fan-out.
-            if many is not None:
-                try:
-                    many(events)
-                except Exception:
-                    pass
-                continue
-            for ev in events:
-                try:
-                    fn(*ev)
-                except Exception:
-                    pass
+            try:
+                fn(events)
+            except Exception:
+                pass
         offered = [w for w in unsharded if not w[2].closed]
         prune = len(offered) != len(unsharded)
         live_buckets: Dict[tuple, List[tuple]] = {}
         memo: Dict[tuple, Any] = {}
-        routed = []  # (version, obj, [(stream, type)]) of taken events
+        routed = []  # (version, etype, obj, [(stream, type)]) taken
         for version, etype, key, obj, prev in events:
             watchers = offered
             if shard_fns:
@@ -962,27 +965,23 @@ class KVStore:
                             live_buckets[(fn, v)] = live
                         watchers.extend(live)
             takers = None
-            for prefix, pred, stream, _shard, extract in watchers:
+            for prefix, pred, stream, _shard, fields in watchers:
                 if not key.startswith(prefix):
                     continue
-                t = etype
-                if pred is not None:
-                    t = _filter_type(pred, extract, memo, etype, obj, prev)
-                    if t is None:
-                        continue
+                t = _filter_type(pred, fields, memo, etype, obj, prev)
+                if t is None:
+                    continue
                 if takers is None:
                     takers = []
-                    routed.append((version, obj, takers))
+                    routed.append((version, etype, obj, takers))
                 takers.append((stream, t))
-        if not routed:
-            if prune:
-                self._prune_closed_watchers()
-            return
         # Copied only if some watcher takes it: most events match few
         # watchers.
-        copies = _copy_objs([obj for _v, obj, _t in routed])
+        copies = _copy_objs([obj for _v, _e, obj, _t in routed])
         out: Dict[WatchStream, List[Event]] = {}
-        for (version, _obj, takers), delivered in zip(routed, copies):
+        for (version, etype, _obj, takers), delivered in zip(routed, copies):
+            if etype == DELETED:
+                self._stamp(delivered, version)
             typed: Dict[str, Event] = {}
             for stream, t in takers:
                 ev = typed.get(t)
@@ -996,6 +995,12 @@ class KVStore:
         for stream, evs in out.items():
             if not stream.push_many(evs):
                 prune = True  # closed, or push_many dropped a slow consumer
+        # Each event's time from its commit to its delivery: the pass
+        # has handed it to every stream that takes it.
+        now = time.monotonic()
+        tracing.observe_waits(
+            "watch.fanout_wait", [now - item[5] for item in items]
+        )
         if prune:
             self._prune_closed_watchers()
 
@@ -1388,24 +1393,19 @@ class KVStore:
 
     # -- Watch --------------------------------------------------------
 
-    def subscribe(
-        self, fn: Optional[Callable] = None, *, many: Optional[Callable] = None
-    ) -> None:
-        """Register an event subscriber, in one of two forms:
-        fn(version, etype, key, obj, prev) is invoked for EVERY event;
-        many(items) once per dispatcher pass, with the pass's events as
-        a list of (version, etype, key, obj, prev) in version order.
-        Either runs on the dispatcher thread, and every subscriber has
-        had the whole pass before any watcher is offered any of it: a
-        cache fed here is at least as fresh as anything a watcher can
-        observe. `obj` is the stored object itself (read-only by
-        contract — subscribers must not mutate and must copy before
-        handing out). This is the apiserver watch cache's feed: one
-        hook, no extra threads, no per-event copies."""
-        if (fn is None) == (many is None):
-            raise TypeError("subscribe() takes exactly one of fn, many")
+    def subscribe(self, fn: Callable) -> None:
+        """Register an event subscriber: fn(items) is invoked once per
+        dispatcher pass, on the dispatcher thread, with the pass's
+        events as a list of (version, etype, key, obj, prev) in version
+        order — together, every event once, in version order. Every
+        subscriber has had the whole pass before any watcher is offered
+        any of it: a cache fed here is at least as fresh as anything a
+        watcher can observe. `obj` is the stored object itself
+        (read-only by contract — subscribers must not mutate and must
+        copy before handing out). This is the apiserver watch cache's
+        feed: one hook, no extra threads, no per-event copies."""
         with self._lock:
-            self._subscribers = self._subscribers + ((fn, many),)
+            self._subscribers = self._subscribers + (fn,)
 
     # -- Replication (store/replication.py rides these seams) ---------
 
@@ -1592,9 +1592,10 @@ class KVStore:
         self,
         prefix: str,
         since: int = 0,
-        maxsize: int = 4096,
-        pred: Optional[Callable[[dict], bool]] = None,
+        maxsize: int = DEFAULT_MAXSIZE,
+        pred: Optional[Callable[..., bool]] = None,
         shard: Optional[tuple] = None,
+        fields: Optional[Callable[[dict], Dict[str, str]]] = None,
     ) -> WatchStream:
         """Stream events for keys under prefix with version > since.
 
@@ -1602,7 +1603,7 @@ class KVStore:
         raises CompactedError — caller must re-list (Reflector does).
         `pred` is a selector filter applied INSIDE the fan-out with
         etcd's modified-out-of-filter -> DELETED translation
-        (_filter_event): non-matching events are never copied or queued
+        (_filter_type): non-matching events are never copied or queued
         for this watcher.
 
         `shard` = (extract_fn, value): a routing hint asserting this
@@ -1614,11 +1615,10 @@ class KVStore:
         be a shared (module-level) callable so equal shards hash
         together.
 
-        A `pred` may expose its field extractor as `pred.extract` (a
-        shared callable, obj -> fields) with `pred.matches(obj,
-        fields)` answering as pred(obj) does: the dispatcher then
-        extracts each object's fields once per pass for every watcher
-        with that extractor.
+        `fields`: a field extractor (obj -> fields), shared like
+        extract_fn. With it, `pred` is called as pred(obj, fields(obj)),
+        and the dispatcher extracts each object's fields once per pass
+        for every watcher with that extractor.
         """
         with self._lock:
             self._expire_locked()
@@ -1640,12 +1640,15 @@ class KVStore:
                 for v, etype, key, obj in self._history:
                     if v > since and key.startswith(prefix):
                         # History has no prev state: replay uses the
-                        # spurious-DELETED degradation (_filter_event).
+                        # spurious-DELETED degradation (_filter_type).
                         # History entries share stored objects, so each
                         # delivery gets its own copy.
-                        ev = _filter_event(pred, etype, obj, None, v)
-                        if ev is not None:
-                            stream.push(Event(ev.type, _copy_obj(obj), v))
+                        t = _filter_type(pred, fields, None, etype, obj, None)
+                        if t is not None:
+                            o = _copy_obj(obj)
+                            if etype == DELETED:
+                                self._stamp(o, v)
+                            stream.push(Event(t, o, v))
             # Replay covered everything <= the current version; the
             # floor makes the dispatcher's not-yet-fanned-out backlog
             # (all <= it, since writes need this lock) a no-op for this
@@ -1655,8 +1658,7 @@ class KVStore:
             self._watchers = [
                 w for w in self._watchers if not w[2].closed
             ]
-            extract = getattr(pred, "extract", None)
-            self._watchers.append((prefix, pred, stream, shard, extract))
+            self._watchers.append((prefix, pred, stream, shard, fields))
             self._rebuild_watch_index_locked()
             return stream
 

@@ -47,6 +47,11 @@ QUEUE_DEPTH = metrics.DEFAULT.gauge(
 )
 
 
+#: Events a watch stream's queue holds before its consumer is dropped as
+#: a slow one (WatchStream.push): the default depth of every watch.
+DEFAULT_MAXSIZE = 4096
+
+
 def resource_of_prefix(prefix: str) -> str:
     """The resource name inside a registry key prefix
     ('/registry/pods/default/' -> 'pods'); the prefix itself when the
@@ -73,7 +78,7 @@ class Event:
 class WatchStream:
     """One consumer's view of a watch. Closed by either side."""
 
-    def __init__(self, maxsize: int = 4096, resource: str = ""):
+    def __init__(self, maxsize: int = DEFAULT_MAXSIZE, resource: str = ""):
         self._q: "queue.Queue[Optional[Event]]" = queue.Queue(maxsize=maxsize)
         self._closed = threading.Event()
         # Version floor: events at or below it are silently dropped.
@@ -203,7 +208,7 @@ class Broadcaster:
         self._lock = sanitizer.lock("watch.broadcaster")
         self._streams: List[WatchStream] = []
 
-    def watch(self, maxsize: int = 4096) -> WatchStream:
+    def watch(self, maxsize: int = DEFAULT_MAXSIZE) -> WatchStream:
         s = WatchStream(maxsize=maxsize, resource="broadcast")
         with self._lock:
             self._streams.append(s)

@@ -203,10 +203,10 @@ class CapacityMonitor:
             shapes = list(self._recent_shapes)
         if shapes:
             arr = np.asarray(shapes, dtype=np.float64)
-            for tag, q in (("p50", 50.0), ("p90", 90.0), ("max", 100.0)):
-                cpu = float(np.ceil(np.percentile(arr[:, 0], q)))
-                mem = float(np.ceil(np.percentile(arr[:, 1], q)))
-                probes.append((f"backlog-{tag}", cpu, mem, 1))
+            # One percentile call for every quantile of both columns.
+            qs = np.ceil(np.percentile(arr, (50.0, 90.0, 100.0), axis=0))
+            for tag, (cpu, mem) in zip(("p50", "p90", "max"), qs):
+                probes.append((f"backlog-{tag}", float(cpu), float(mem), 1))
         return probes
 
     # -- sampling ----------------------------------------------------------
@@ -358,10 +358,11 @@ class CapacityMonitor:
             vals = ratios[live_idx]
             if not len(vals):
                 return {"mean": 0.0, "p50": 0.0, "p99": 0.0}
+            p50, p99 = np.percentile(vals, (50, 99))
             return {
                 "mean": round(float(vals.mean()), 6),
-                "p50": round(float(np.percentile(vals, 50)), 6),
-                "p99": round(float(np.percentile(vals, 99)), 6),
+                "p50": round(float(p50), 6),
+                "p99": round(float(p99), 6),
             }
 
         node_util = {}

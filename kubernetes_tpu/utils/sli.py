@@ -233,12 +233,9 @@ class LifecycleSLICollector:
         """Subscribe to a kvstore's event dispatcher (the same feed the
         apiserver watch cache rides). Idempotent per store: KVStore
         subscribers are append-only, so attach once per store."""
-        store.subscribe(many=self._on_store_events)
+        store.subscribe(self._on_store_events)
 
     # -- event feed (dispatcher thread) --------------------------------
-
-    def _on_store_event(self, version, etype, key, obj, prev) -> None:
-        self._on_store_events(((version, etype, key, obj, prev),))
 
     def _on_store_events(self, items) -> None:
         """One dispatcher pass, in version order: one clock read, one

@@ -295,3 +295,46 @@ class TestDaemonRegressions:
         cfg.node_lister.get = lambda name: (_ for _ in ()).throw(KeyError(name))
         assert sched.schedule_one(timeout=1) is True  # no crash
         cfg.stop()
+
+    def test_one_pod_watch_splits_by_binding(self):
+        """The scheduler's one pod watch feeds the FIFO with unbound
+        pods and the scheduled cache with bound ones; a bind moves a
+        pod across (out of the FIFO, MODIFIED into the cache), a delete
+        leaves the side it was on, and a relist replaces both sides,
+        with DELETED for every pod that vanished: scheduled, or pending
+        and possibly placed by a tick whose bind the watch has not
+        shown yet."""
+        from kubernetes_tpu.client.cache import FIFO, ThreadSafeStore
+        from kubernetes_tpu.scheduler.daemon import _PodSplit
+
+        emitted = []
+        queue, scheduled = FIFO(), ThreadSafeStore()
+        split = _PodSplit(
+            queue, scheduled, decode_scheduled=None,
+            emit=lambda etype, obj: emitted.append(
+                (etype, obj["metadata"]["name"])
+            ),
+        )
+
+        def bound(name, node):
+            p = pod_wire(name)
+            p["spec"]["nodeName"] = node
+            return p
+
+        split.add(pod_wire("a"))
+        split.add(pod_wire("b"))
+        split.add(bound("c", "n1"))
+        split.add(pod_wire("f"))
+        assert len(queue) == 3 and scheduled.keys() == ["default/c"]
+        split.update(bound("a", "n1"))  # the bind
+        assert len(queue) == 2 and sorted(scheduled.keys()) == ["default/a", "default/c"]
+        split.delete(pod_wire("b"))
+        split.delete(bound("c", "n1"))
+        assert len(queue) == 1 and scheduled.keys() == ["default/a"]
+        assert emitted == [("ADDED", "c"), ("MODIFIED", "a"), ("DELETED", "c")]
+        emitted.clear()
+        assert queue.pop(timeout=0).metadata.name == "f"  # a tick took f
+        split.replace([pod_wire("d"), bound("e", "n2")])
+        assert queue.pop(timeout=0).metadata.name == "d"
+        assert scheduled.keys() == ["default/e"]
+        assert emitted == [("DELETED", "f"), ("DELETED", "a"), ("ADDED", "e")]

@@ -39,6 +39,11 @@ def _key(name, ns="default"):
     return f"{sli.POD_PREFIX}{ns}/{name}"
 
 
+def _feed(c, version, etype, key, obj, prev):
+    """One event, as a dispatcher pass of one."""
+    c._on_store_events([(version, etype, key, obj, prev)])
+
+
 class TestLifecycleCollector:
     def test_milestones_observed_in_order(self):
         c = sli.LifecycleSLICollector()
@@ -46,13 +51,13 @@ class TestLifecycleCollector:
             m: sli.STARTUP_LATENCY.count(milestone=m)
             for m in ("decision", "bound", "running")
         }
-        c._on_store_event(1, "ADDED", _key("p1"), _pod_wire("p1"), None)
+        _feed(c, 1, "ADDED", _key("p1"), _pod_wire("p1"), None)
         assert c.tracked_count() == 1
         c.note_decision("default/p1", "bound")
-        c._on_store_event(
+        _feed(c, 
             2, "MODIFIED", _key("p1"), _pod_wire("p1", node="n0"), None
         )
-        c._on_store_event(
+        _feed(c, 
             3, "MODIFIED", _key("p1"),
             _pod_wire("p1", node="n0", phase="Running"), None,
         )
@@ -66,9 +71,9 @@ class TestLifecycleCollector:
     def test_milestones_are_first_transition_only(self):
         c = sli.LifecycleSLICollector()
         before = sli.STARTUP_LATENCY.count(milestone="bound")
-        c._on_store_event(1, "ADDED", _key("p2"), _pod_wire("p2"), None)
+        _feed(c, 1, "ADDED", _key("p2"), _pod_wire("p2"), None)
         for v in (2, 3, 4):
-            c._on_store_event(
+            _feed(c, 
                 v, "MODIFIED", _key("p2"), _pod_wire("p2", node="n0"), None
             )
         assert sli.STARTUP_LATENCY.count(milestone="bound") == before + 1
@@ -79,10 +84,10 @@ class TestLifecycleCollector:
 
     def test_born_bound_and_foreign_keys_ignored(self):
         c = sli.LifecycleSLICollector()
-        c._on_store_event(
+        _feed(c, 
             1, "ADDED", _key("static"), _pod_wire("static", node="n0"), None
         )
-        c._on_store_event(
+        _feed(c, 
             2, "ADDED", "/registry/nodes/n0", {"metadata": {"name": "n0"}},
             None,
         )
@@ -90,8 +95,8 @@ class TestLifecycleCollector:
 
     def test_deleted_forgets_and_decision_for_unknown_is_noop(self):
         c = sli.LifecycleSLICollector()
-        c._on_store_event(1, "ADDED", _key("p3"), _pod_wire("p3"), None)
-        c._on_store_event(2, "DELETED", _key("p3"), _pod_wire("p3"), None)
+        _feed(c, 1, "ADDED", _key("p3"), _pod_wire("p3"), None)
+        _feed(c, 2, "DELETED", _key("p3"), _pod_wire("p3"), None)
         assert c.tracked_count() == 0
         before = sli.STARTUP_LATENCY.count(milestone="decision")
         c.note_decision("default/p3")
@@ -101,13 +106,13 @@ class TestLifecycleCollector:
         c = sli.LifecycleSLICollector()
         c.MAX_TRACKED = 4
         for i in range(10):
-            c._on_store_event(
+            _feed(c, 
                 i + 1, "ADDED", _key(f"b{i}"), _pod_wire(f"b{i}"), None
             )
         assert c.tracked_count() == 4
         # The survivors are the NEWEST four.
         before = sli.STARTUP_LATENCY.count(milestone="bound")
-        c._on_store_event(
+        _feed(c, 
             99, "MODIFIED", _key("b9"), _pod_wire("b9", node="n0"), None
         )
         assert sli.STARTUP_LATENCY.count(milestone="bound") == before + 1
@@ -115,7 +120,7 @@ class TestLifecycleCollector:
     def test_disabled_collector_ignores_events(self):
         c = sli.LifecycleSLICollector()
         c.enabled = False
-        c._on_store_event(1, "ADDED", _key("off"), _pod_wire("off"), None)
+        _feed(c, 1, "ADDED", _key("off"), _pod_wire("off"), None)
         assert c.tracked_count() == 0
 
 
@@ -380,7 +385,7 @@ class TestEndToEnd:
             staleness = {
                 r for (r,) in sli.INFORMER_STALENESS.label_values()
             }
-            assert {"nodes", "pods_pending"} <= staleness
+            assert {"nodes", "pods"} <= staleness
 
             # ktctl slo (LocalTransport: evaluates the local engine).
             out = io.StringIO()
@@ -584,7 +589,7 @@ class TestOverheadGuard:
             c = sli.LifecycleSLICollector()
             t0 = time.perf_counter()
             for etype, key, obj in events:
-                c._on_store_event(1, etype, key, obj, None)
+                _feed(c, 1, etype, key, obj, None)
             for _ in range(2 * n_pods // batch):
                 sli.observe_device_telemetry()
             sli_cost = min(sli_cost, time.perf_counter() - t0)

@@ -171,7 +171,7 @@ def test_slow_consumer_stream_closed():
 
 class TestFilteredWatch:
     """Store-level selector filtering with etcd's old/new-aware
-    translation (kvstore._filter_event; reference:
+    translation (kvstore._filter_type; reference:
     pkg/tools/etcd_helper_watch.go sendModify/sendDelete). The filter
     runs INSIDE the fan-out, so a watcher is never even offered events
     for objects that don't concern it."""
