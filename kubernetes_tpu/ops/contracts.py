@@ -175,6 +175,9 @@ DIM_LATTICES: Dict[str, Tuple[str, object]] = {
     "A": ("policy affinity axis: >= 1", lambda n: n >= 1),
     "Z": ("policy zone axis: >= 1", lambda n: n >= 1),
     "S1": ("service axis + scratch slot: >= 2", lambda n: n >= 2),
+    # The pallas kernel's service-count carry: whole 8-row bands
+    # (pallas_scan._svc_pad).
+    "SB": ("service bands: multiple of 8", lambda n: n >= 8 and n % 8 == 0),
 }
 
 
@@ -310,6 +313,10 @@ def _b8(*dims, optional=False):
     return ArraySpec(tuple(dims), "b8", optional)
 
 
+def _i16(*dims, optional=False):
+    return ArraySpec(tuple(dims), "i16", optional)
+
+
 #: The pod-column schema every solver-family kernel consumes
 #: (matrices.device_pods). aff_pin rides only when ServiceAffinity is
 #: lowered.
@@ -373,6 +380,15 @@ _SOLVE_SAMPLES = (
     {"P": 128, "N": 128, "LW": 2, "PW": 2, "VW": 2, "K": SVC_K, "S": 128},
     {"P": 512, "N": 256, "LW": 4, "PW": 2, "VW": 2, "K": SVC_K, "S": 128},
 )
+
+#: The pallas kernel's per-node columns: NODE_SCHEMA's non-optional
+#: leaves but the service counts, which travel on their own as the
+#: banded (SB, N) int16 carry.
+_KERNEL_NODES = {
+    k: v for k, v in NODE_SCHEMA.items()
+    if not v.optional and k != "svc_counts"
+}
+_PALLAS_SAMPLES = tuple(dict(s, SB=s["S"]) for s in _SOLVE_SAMPLES)
 
 _WAVE_TELEMETRY = (_i32(), _i32(), _f32())  # waves, iters, residual
 
@@ -515,15 +531,19 @@ CONTRACTS: Dict[str, Contract] = {
         kernel="pallas_scan._solve_packed",
         args=(
             ("pods", POD_SCHEMA),
-            ("nodes", NODE_SCHEMA),
+            ("nodes", _KERNEL_NODES),
+            ("counts", _i16("SB", "N")),
             ("weights", Static((1, 1, 1))),
         ),
-        results=(_i32("P"), NODE_SCHEMA),
+        results=(_i32("P"), _KERNEL_NODES, _i16("SB", "N")),
         pod_dim="P",
         pod_axis="reduces",
-        samples=_SOLVE_SAMPLES,
+        samples=_PALLAS_SAMPLES,
         kwargs=(("interpret", Static(False)),),
-        notes="whole sequential solve as one pallas_call (VMEM carry)",
+        notes=(
+            "whole sequential solve as one pallas_call (VMEM node "
+            "carry, HBM service-count bands)"
+        ),
         sharding=MeshSharding(
             dim=None, axis="nodes",
             budget=CommBudget(),
@@ -580,6 +600,34 @@ CONTRACTS: Dict[str, Contract] = {
                 "dirty-row scatter stays replicated: sharding the "
                 "node axis would turn every row patch into a "
                 "collective-permute round on the micro-tick path"
+            ),
+        ),
+    ),
+    "pallas_scan.scatter_rows_banded": Contract(
+        kernel="pallas_scan.scatter_rows_banded",
+        args=(
+            ("counts", _i16("SB", "N")),
+            ("svc", _i32("R")),
+            ("slot", _i32("R")),
+            ("value", _i16("R")),
+        ),
+        results=_i16("SB", "N"),
+        pod_dim=None,
+        pod_axis="replicated",
+        samples=(
+            {"SB": 8, "N": 128, "R": 8},
+            {"SB": 648, "N": 256, "R": 64},
+        ),
+        notes=(
+            "point patch of the banded service-count carry (the "
+            "dirty-row scatter's count half); never sees the pod axis"
+        ),
+        sharding=MeshSharding(
+            dim=None, axis="nodes",
+            budget=CommBudget(),
+            notes=(
+                "stays replicated, as the row scatter it completes: "
+                "the banded carry lives on one chip"
             ),
         ),
     ),
@@ -739,7 +787,7 @@ def _distinct_bindings(contract: Contract) -> Dict[str, int]:
     pool = {
         "P": 384, "PG": 24, "G": 48, "N": 256, "LW": 2, "PW": 4, "VW": 6,
         "S": 640, "K": SVC_K, "V": 40, "M": 16, "R": 12,
-        "A": 3, "Z": 5, "S1": 641, "Q": 32, "D": 64,
+        "A": 3, "Z": 5, "S1": 641, "Q": 32, "D": 64, "SB": 648,
     }
     return {s: pool[s] for s in symbols if s in pool}
 

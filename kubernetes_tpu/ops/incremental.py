@@ -12,6 +12,15 @@ accelerator:
 - pod deletions touch one node row each: the host mirror recomputes
   that row (greedy-fit order, reference MapPodsToMachines semantics)
   and a jitted scatter patches just those rows on device;
+- where the pallas kernel takes the ticks (one TPU, shapes it fits),
+  the service counts live on the device in the kernel's own layout,
+  (S_pad, N) int16 (ops/pallas_scan.py ``to_bands``), so a tick moves
+  only the bands it touches and a delete writes only the (Service,
+  node) entries it changed; the host mirror keeps the XLA scan's
+  (N, S) f32 schema, converted at the one full upload;
+- the flight recorder's verdict tables (``explain``) read the host
+  mirror: the node rows and the Service columns the explained pods
+  read, never a re-lowering of the cluster's bound pods;
 - pending pods are transient per tick and upload as small bucketed
   arrays (bucket sizes limit XLA recompiles; SURVEY.md hard part (d)).
 
@@ -52,9 +61,17 @@ from kubernetes_tpu.models.objects import (
     Pod,
     Service,
 )
+from kubernetes_tpu.ops import pallas_scan
 from kubernetes_tpu.ops.ledger import traced_jit
 from kubernetes_tpu.ops.matrices import SVC_K
 from kubernetes_tpu.ops.solver import DEFAULT_WEIGHTS, solve_with_state
+from kubernetes_tpu.utils import metrics
+
+#: Device bytes of the newest session's service-count carry.
+CARRY_BYTES = metrics.DEFAULT.gauge(
+    "scheduler_spreading_carry_bytes",
+    "Device bytes of the incremental session's service-count carry.",
+)
 
 
 class RebuildRequired(Exception):
@@ -220,8 +237,20 @@ class SolverSession:
                 self._recompute_node_row(j)
 
         self._pending: List[_LoweredPod] = []
+        # Which kernel runs the scan ticks decides the carry's layout:
+        # banded (the pallas kernel's) when it takes them.
+        self.kernel_reason = ""
+        if mode == "scan":
+            self.kernel_reason = pallas_scan.session_reason(
+                mesh, self.N_cap, self.LW, self.PW, self.VW
+            )
+        self.banded = mode == "scan" and not self.kernel_reason
         self.dev = self._upload_all()
+        CARRY_BYTES.set(self.dev["svc_counts"].nbytes)
         self._dirty: set = set()
+        # (Service, node slot) count entries the dirty rows changed:
+        # what a banded carry's delete patch writes.
+        self._dirty_counts: set = set()
         # Convergence telemetry of the most recent solve() tick — the
         # incremental daemon folds this into its SolveRecord.
         self.last_stats: Dict[str, float] = {}
@@ -318,12 +347,16 @@ class SolverSession:
         self._node_specs[j] = node
         return j
 
-    def _recompute_node_row(self, j: int) -> None:
+    def _recompute_node_row(
+        self, j: int, h: Optional[Dict] = None, skip=frozenset()
+    ) -> None:
         """Rebuild slot j's full row from spec + assigned pods (the
         only non-monotonic operation: deletes can't be expressed as
-        bitset increments)."""
+        bitset increments). ``h`` is the host mirror unless given (the
+        explain view passes copies of some columns); pods in ``skip``
+        are left out."""
         node = self._node_specs[j]
-        h = self.h
+        h = self.h if h is None else h
         for k in h:
             h[k][j] = 0
         if node is None:
@@ -344,6 +377,8 @@ class SolverSession:
         )
         h["sched"][j] = node_is_ready(node)
         for lp in self._assigned[j]:
+            if lp.key in skip:
+                continue
             # Greedy-fit order = arrival order (reference semantics).
             fits_cpu = h["cpu_cap"][j] == 0 or (
                 h["cpu_fit"][j] + lp.cpu <= h["cpu_cap"][j]
@@ -362,8 +397,14 @@ class SolverSession:
             h["uport"][j] |= bitset(lp.port_ids, self.PW)
             h["uvol_any"][j] |= bitset(lp.vol_any_ids, self.VW)
             h["uvol_rw"][j] |= bitset(lp.vol_rw_ids, self.VW)
-            if len(lp.svc_topk):
+            if len(lp.svc_topk) and "svc_counts" in h:
                 h["svc_counts"][j, lp.svc_topk] += 1.0
+
+    def _charge_changed(self, j: int, lp: _LoweredPod) -> None:
+        """Pod ``lp`` joined or left slot j: its row and its Service
+        count entries there are dirty."""
+        self._dirty.add(j)
+        self._dirty_counts.update((int(s), j) for s in lp.svc_topk)
 
     def _apply_commit_host(self, j: int, lp: _LoweredPod) -> None:
         """Mirror of solver._commit — keeps host state bit-identical to
@@ -382,33 +423,57 @@ class SolverSession:
 
     # -- device transfer ----------------------------------------------
 
+    def _row_keys(self) -> List[str]:
+        """The leaves the dirty-row scatter patches by row: all of them,
+        but a banded carry's service counts (patched by entry)."""
+        return [k for k in self.h if not (self.banded and k == "svc_counts")]
+
     def _upload_all(self) -> Dict[str, jnp.ndarray]:
         from kubernetes_tpu.utils import sli
 
-        sli.note_transfer("h2d", sli.nbytes_of(self.h))
+        cols = self.h
+        if self.banded:
+            cols = dict(cols, svc_counts=pallas_scan.to_bands(cols["svc_counts"]))
+        sli.note_transfer("h2d", sli.nbytes_of(cols))
         if self.mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec as PS
 
             sharding = NamedSharding(self.mesh, PS("nodes"))
-            return {k: jax.device_put(v, sharding) for k, v in self.h.items()}
-        return {k: jnp.asarray(v) for k, v in self.h.items()}
+            return {k: jax.device_put(v, sharding) for k, v in cols.items()}
+        return {k: jnp.asarray(v) for k, v in cols.items()}
 
     def _flush_dirty(self) -> None:
         if not self._dirty:
             return
-        idx = sorted(self._dirty)
-        self._dirty.clear()
-        # Bucket the scatter width: pad by repeating the last index
-        # (identical row, harmless duplicate) so recompiles are rare.
-        width = _bucket(len(idx), minimum=8)
-        padded = idx + [idx[-1]] * (width - len(idx))
-        rows = {k: self.h[k][padded] for k in self.h}
-        from kubernetes_tpu.utils import sli
+        from kubernetes_tpu.utils import sli, tracing
 
-        sli.note_transfer("h2d", sli.nbytes_of(rows))
-        self.dev = _scatter_rows(
-            self.dev, jnp.asarray(padded, dtype=jnp.int32), rows
-        )
+        idx = sorted(self._dirty)
+        points = sorted(self._dirty_counts)
+        self._dirty.clear()
+        self._dirty_counts.clear()
+        with tracing.phase("scatter", rows=len(idx)):
+            # Bucket the scatter width: pad by repeating the last index
+            # (identical row, harmless duplicate) so recompiles are rare.
+            width = _bucket(len(idx), minimum=8)
+            padded = idx + [idx[-1]] * (width - len(idx))
+            keys = self._row_keys()
+            rows = {k: self.h[k][padded] for k in keys}
+            sli.note_transfer("h2d", sli.nbytes_of(rows))
+            dev = _scatter_rows(
+                {k: self.dev[k] for k in keys},
+                jnp.asarray(padded, dtype=jnp.int32), rows,
+            )
+            if self.banded:
+                counts = self.dev["svc_counts"]
+                if points:
+                    staged = pallas_scan.count_points(
+                        self.h["svc_counts"], points,
+                        _bucket(len(points), minimum=8),
+                    )
+                    sli.note_transfer("h2d", sli.nbytes_of(staged))
+                    counts = pallas_scan.scatter_rows_banded(counts, *staged)
+                dev["svc_counts"] = counts
+            self.dev = dev
 
     # -- public API ---------------------------------------------------
 
@@ -431,6 +496,7 @@ class SolverSession:
         self._node_specs[j] = None
         for lp in self._assigned[j]:
             self._pod_node.pop(lp.key, None)
+            self._charge_changed(j, lp)
         self._assigned[j] = []
         self._recompute_node_row(j)  # zeroes the row; sched stays False
         self._dirty.add(j)
@@ -453,7 +519,7 @@ class SolverSession:
         self._assigned[j].append(lp)
         self._pod_node[lp.key] = j
         self._recompute_node_row(j)
-        self._dirty.add(j)
+        self._charge_changed(j, lp)
         return True
 
     def has_assigned(self, key: str) -> bool:
@@ -464,9 +530,11 @@ class SolverSession:
         j = self._pod_node.pop(key, None)
         if j is None:
             return False
+        for lp in self._assigned[j]:
+            if lp.key == key:
+                self._charge_changed(j, lp)
         self._assigned[j] = [lp for lp in self._assigned[j] if lp.key != key]
         self._recompute_node_row(j)
-        self._dirty.add(j)
         return True
 
     def _dispatch(self, pods, carry):
@@ -486,6 +554,11 @@ class SolverSession:
 
             assignment, carry, waves, s_iters, s_res = (
                 solve_sinkhorn_with_state(pods, carry, self.weights)
+            )
+        elif self.banded:
+            pallas_scan.count_solve("")
+            assignment, carry = pallas_scan.solve_banded(
+                pods, carry, self.weights
             )
         else:
             assignment, carry = solve_with_state(pods, carry, self.weights)
@@ -594,25 +667,28 @@ class SolverSession:
         return self.solve_async().result()
 
     def prewarm(
-        self, max_pod_bucket: int = 0, max_scatter_width: int = 512
+        self, max_pod_bucket: int = 0, max_scatter_width: int = 512,
+        explain_pods: int = 0,
     ) -> int:
         """Compile every executable a live tick can hit — the solve at
-        each pow2 pod bucket up to max_pod_bucket and the dirty-row
-        scatter at each pow2 width — against THROWAWAY copies of the
-        node state, so the process-global XLA cache is hot before the
-        first real pod arrives (a fresh bucket mid-workload otherwise
-        stalls that tick for a full compile: seconds on TPU, minutes on
-        CPU hosts). Returns the number of warm dispatches issued."""
+        each pow2 pod bucket up to max_pod_bucket, the dirty-row
+        scatter at each pow2 width (by row, and on a banded carry by
+        count entry) and the ``explain`` readback at each pow2 bucket
+        up to explain_pods — against THROWAWAY copies of the node
+        state, so the process-global XLA cache is hot before the first
+        real pod arrives (a fresh bucket mid-workload otherwise stalls
+        that tick for a full compile: seconds on TPU, minutes on CPU
+        hosts). Returns the number of warm dispatches issued."""
         compiled = 0
         bucket = max(_bucket(1), self.pod_bucket)
         top = max(bucket, _bucket(max_pod_bucket)) if max_pod_bucket else 0
         while bucket <= top:
             pods = self._stage_arrays([], bucket, reuse=False)
-            # Throwaway carries go through _upload_all: identical
-            # sharding (mesh sessions included) to the live self.dev —
+            # Throwaway carries are device copies of the live self.dev:
+            # identical layout and sharding (mesh sessions included) —
             # a differently-placed warm carry would compile a cache
             # entry the real ticks never hit.
-            carry = self._upload_all()
+            carry = self._copy_dev()
             assignment, carry, _tele = self._dispatch(pods, carry)
             jax.block_until_ready(assignment)
             del carry
@@ -623,16 +699,93 @@ class SolverSession:
             (j for j, n in enumerate(self.node_names) if n is not None),
             default=0,
         )
+        keys = self._row_keys()
         while width <= min(max_scatter_width, self.N_cap):
             idx = np.full(width, idx_max, np.int32)
-            rows = {k: self.h[k][idx] for k in self.h}
-            carry = self._upload_all()
+            rows = {k: self.h[k][idx] for k in keys}
+            carry = self._copy_dev(keys)
             out = _scatter_rows(carry, jnp.asarray(idx), rows)
             jax.block_until_ready(jax.tree_util.tree_leaves(out))
+            compiled += 1
+            if self.banded:
+                staged = pallas_scan.count_points(
+                    self.h["svc_counts"], [(0, int(idx_max))], width
+                )
+                counts = self._copy_dev(["svc_counts"])["svc_counts"]
+                jax.block_until_ready(
+                    pallas_scan.scatter_rows_banded(counts, *staged)
+                )
+                compiled += 1
             del carry, out
+            width *= 2
+        width = 8
+        while width <= _bucket(explain_pods, minimum=8) and explain_pods:
+            self.explain([], bucket=width)
             compiled += 1
             width *= 2
         return compiled
+
+    def explain(
+        self, pods: Sequence[Pod], release: Sequence[str] = (),
+        bucket: int = 0,
+    ):
+        """Per-node verdicts for ``pods`` against the cluster as the
+        host mirror holds it now, with the charges of the pods keyed
+        ``release`` taken off their nodes (a bound pod's view before
+        its own bind): ``ops.pipeline.explain_matrix``'s (node names,
+        bits u32[P, N], components) over the live node slots, in slot
+        order (the solve's tie-break order). One dispatch over the node
+        rows and only the Service columns the pods read — never a
+        re-lowering of the cluster's bound pods. Lowers through the
+        session's vocabularies: the solve loop's thread only."""
+        from dataclasses import replace
+
+        from kubernetes_tpu.ops.solver import explain_rows
+
+        lowered = [self._lower_pod(pod) for pod in pods]
+        PP = max(_bucket(len(lowered), minimum=8), bucket)
+        ids = sorted({lp.svc for lp in lowered if lp.svc >= 0})
+        col = {s: c for c, s in enumerate(ids)}
+        svc = np.zeros((self.N_cap, PP), np.float32)
+        svc[:, : len(ids)] = self.h["svc_counts"][:, ids]
+        view = {k: v for k, v in self.h.items() if k != "svc_counts"}
+        gone = [
+            (self._pod_node[k], k) for k in release if k in self._pod_node
+        ]
+        if gone:
+            view = {k: v.copy() for k, v in view.items()}
+            skip = {k for _, k in gone}
+            for j in {j for j, _ in gone}:
+                self._recompute_node_row(j, view, skip)
+            for j, key in gone:
+                for lp in self._assigned[j]:
+                    if lp.key == key:
+                        for s in lp.svc_topk.tolist():
+                            if s in col:
+                                svc[j, col[s]] -= 1.0
+        view["svc_counts"] = svc
+        staged = [
+            replace(lp, svc=col.get(lp.svc, -1), svc_topk=lp.svc_topk[:0])
+            for lp in lowered
+        ]
+        bits, lr, bra, spread = explain_rows(
+            self._stage_arrays(staged, PP, reuse=False),
+            {k: jnp.asarray(v) for k, v in view.items()},
+        )
+        live = [j for j, n in enumerate(self.node_names) if n is not None]
+        P = len(lowered)
+        return (
+            [self.node_names[j] for j in live],
+            np.asarray(bits)[:P][:, live],
+            {
+                "leastRequested": np.asarray(lr)[:P][:, live],
+                "balanced": np.asarray(bra)[:P][:, live],
+                "spreading": np.asarray(spread)[:P][:, live],
+            },
+        )
+
+    def _copy_dev(self, keys=None) -> Dict[str, jnp.ndarray]:
+        return {k: jnp.copy(self.dev[k]) for k in (keys or self.dev)}
 
     def solve_gang(
         self, gangs: Sequence[SessionGang]

@@ -322,6 +322,17 @@ def scatter_rows_numpy(
     return out
 
 
+def scatter_rows_banded_numpy(
+    counts: np.ndarray, svc: np.ndarray, slot: np.ndarray, value: np.ndarray
+) -> np.ndarray:
+    """NumPy twin of ops.pallas_scan.scatter_rows_banded (the banded
+    carry's point patch): out-of-place (Service, node slot) writes.
+    Registered in ops/parity.py; parity pinned by tests/test_ktsan.py."""
+    out = np.array(counts, copy=True)
+    out[np.asarray(svc), np.asarray(slot)] = np.asarray(value)
+    return out
+
+
 def validate_assignment_numpy(snap: Snapshot, assignment) -> None:
     """Replay every placement against the snapshot's own predicate
     semantics in NumPy; raises AssertionError on any capacity /

@@ -62,6 +62,10 @@ ORACLE_TWINS = {
         "suite": "tests/test_pallas_scan.py",
         "exercised_as": "solve_with_state_pallas",
     },
+    "pallas_scan.scatter_rows_banded": {
+        "oracle": "ops.oracle.scatter_rows_banded_numpy",
+        "suite": "tests/test_ktsan.py",
+    },
     "preemption._victim_prefix_kernel.kernel": {
         "oracle": "scheduler.batch.preempt_backlog_scalar",
         "suite": "tests/test_solver_parity.py",

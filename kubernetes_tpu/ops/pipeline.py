@@ -250,18 +250,32 @@ def explain_backlog(
     max_failed individually-listed infeasible nodes, and aggregate
     failed-predicate counts over ALL nodes — a 5k-node cluster folds
     into a handful of reason counts, not 5k rows."""
+    pending = list(pending)
+    if not pending:
+        return []
+    return explain_entries(
+        pending,
+        *explain_matrix(pending, nodes, assigned, services, mesh=mesh),
+        top_k=top_k, max_failed=max_failed,
+    )
+
+
+def explain_entries(
+    pending: Sequence[Pod],
+    names: Sequence[str],
+    bits: np.ndarray,
+    comps: Dict[str, np.ndarray],
+    top_k: int = 3,
+    max_failed: int = 16,
+) -> List[dict]:
+    """explain_backlog's verdicts from an explain readback already
+    taken (``explain_matrix``, or a SolverSession's ``explain``)."""
     from kubernetes_tpu.models.objects import pod_full_key
     from kubernetes_tpu.ops.matrices import (
         EXPLAIN_PREDICATES,
         decode_predicate_bits,
     )
 
-    pending = list(pending)
-    if not pending:
-        return []
-    names, bits, comps = explain_matrix(
-        pending, nodes, assigned, services, mesh=mesh
-    )
     total = (
         comps["leastRequested"] + comps["balanced"] + comps["spreading"]
     )

@@ -337,9 +337,10 @@ def _solve_with_state_xla(pods, nodes, weights, lspec):
 
 
 def _use_pallas(pods, nodes, lspec) -> bool:
-    from kubernetes_tpu.ops.pallas_scan import pallas_eligible
+    """Pick the kernel for one solve, and count it."""
+    from kubernetes_tpu.ops.pallas_scan import count_solve, pallas_reason
 
-    return pallas_eligible(pods, nodes, lspec)
+    return count_solve(pallas_reason(nodes, lspec))
 
 
 def solve(
@@ -355,7 +356,7 @@ def solve(
 
     Dispatch: the default spec on a single unsharded TPU device runs
     the pallas kernel (ops/pallas_scan.py — same decisions, ~3x faster:
-    the whole occupancy carry lives in VMEM instead of round-tripping
+    the per-node occupancy carry lives in VMEM instead of round-tripping
     HBM every scan step). Policy specs, meshes, and CPU run the XLA
     scan. Bit-identical by test (tests/test_pallas_scan.py) and by the
     bench's measured sequential-oracle parity chain."""

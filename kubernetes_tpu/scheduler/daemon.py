@@ -13,6 +13,7 @@ Two operating modes share this daemon:
 
 from __future__ import annotations
 
+import gc
 import logging
 import queue
 import threading
@@ -1727,11 +1728,6 @@ class IncrementalBatchScheduler(BatchScheduler):
             try:
                 job = self._commit_q.get(timeout=0.1)
             except queue.Empty:
-                # Idle gap: attach deferred bound-pod verdict tables
-                # (runs concurrently with the solve loop — on a busy
-                # box GIL contention beats serializing the dispatch
-                # onto the bind path).
-                self._run_deferred_explain()
                 continue
             try:
                 if job is None:
@@ -1838,8 +1834,8 @@ class IncrementalBatchScheduler(BatchScheduler):
         # (~45ms on CPU hosts) and even a "cluster looks quiet right
         # now" inline capture lands squarely on the next arrival's
         # bind latency. Unbound pods still capture inline (operators
-        # explain THOSE); bound tables attach post-hoc from the commit
-        # worker's idle drain. Manual (non-started) ticks keep the
+        # explain THOSE); bound tables attach post-hoc from the solve
+        # loop's idle drain. Manual (non-started) ticks keep the
         # synchronous full capture.
         return self._pipelined
 
@@ -1847,20 +1843,21 @@ class IncrementalBatchScheduler(BatchScheduler):
         self._deferred_explain.append(ctx)
 
     #: Seconds the solve loop must be quiet before deferred bound-pod
-    #: tables attach — the capture's Python-side snapshot build would
-    #: otherwise contend (GIL) with live ticks on small hosts.
+    #: tables attach: a burst's ticks come first.
     _EXPLAIN_QUIET_S = 0.5
 
     def _run_deferred_explain(self) -> None:
-        """Worker-idle half of verdict capture: attach bound-pod
-        tables to Decision records already in the ring, but only once
-        the solve loop has been quiet for a beat. Best-effort by
-        design — the deque is bounded (newest ticks win: a cluster
-        saturated forever keeps only its latest tables), and the
-        occupancy view is read at attach time, so tables reflect the
-        cluster as of shortly after the bind (the exact pre/post-solve
-        states remain on the synchronous path). Unbound pods never
-        wait on this — their tables capture inline."""
+        """Idle half of verdict capture, on the solve loop's own thread
+        between ticks: attach bound-pod tables to Decision records
+        already in the ring, but only once the loop has been quiet for
+        a beat. Best-effort by design — the deque is bounded (newest
+        ticks win: a cluster saturated forever keeps only its latest
+        tables), and the occupancy view is read at attach time from the
+        session's host mirror, so tables reflect the cluster as of
+        shortly after the bind, less the explained pods' own charges
+        (the exact pre/post-solve states remain on the synchronous
+        path). Unbound pods never wait on this — their tables capture
+        inline."""
         if not self._deferred_explain:
             return
         if (
@@ -1881,6 +1878,55 @@ class IncrementalBatchScheduler(BatchScheduler):
                 )
         except Exception:
             _LOG.debug("deferred explain capture failed", exc_info=True)
+
+    def _attach_verdicts(
+        self, rows, decisions, nodes, services, assigned_pre, limit,
+        only: Optional[str] = None,
+    ) -> None:
+        """The deferred bound half reads the session's host mirror
+        (``SolverSession.explain``): its cost is the explained pods',
+        not the cluster's — re-lowering every bound pod took seconds of
+        the interpreter at 150,000 pods. Everything else, or with no
+        session, takes the snapshot path."""
+        session = self._session
+        if only != "bound" or session is None:
+            return super()._attach_verdicts(
+                rows, decisions, nodes, services, assigned_pre, limit,
+                only=only,
+            )
+        import copy
+
+        from kubernetes_tpu.ops.pipeline import explain_entries
+
+        bound = []
+        for pod, dest, _, _ in rows:
+            if dest is None or len(bound) >= limit:
+                continue
+            # Bound this tick: explain the pre-bind view, or the
+            # HostName predicate would pin the verdict to the answer.
+            ep = copy.deepcopy(pod)
+            ep.spec.node_name = ""
+            bound.append(ep)
+        if not bound:
+            return
+        names, bits, comps = session.explain(
+            bound, release=[self._pod_key(p) for p in bound]
+        )
+        for entry in explain_entries(
+            bound, names, bits, comps,
+            top_k=flightrecorder.explain_top_k(),
+            max_failed=flightrecorder.explain_failed_nodes(),
+        ):
+            d = decisions.get(entry["pod"])
+            if d is not None:
+                d.attach(entry)
+
+    #: Bound pods from which a session build also settles the heap: the
+    #: process's long-lived objects (the caches of every bound pod) move
+    #: out of the cyclic collector's reach (``gc.freeze``). At 150,000
+    #: pods one full collection re-walked them for 1-2 s of the
+    #: interpreter, a stall on every thread, the apiserver's included.
+    FREEZE_HEAP_PODS = 50_000
 
     def _build_session(self):
         from kubernetes_tpu.ops import SolverSession
@@ -1915,12 +1961,22 @@ class IncrementalBatchScheduler(BatchScheduler):
         )
         if self.prewarm_buckets:
             t0 = time.monotonic()
-            n = session.prewarm(self.prewarm_buckets)
+            limit = flightrecorder.explain_limit()
+            explain = self.spec is None and self.sidecar is None
+            n = session.prewarm(
+                self.prewarm_buckets,
+                explain_pods=limit if explain and limit > 0 else 0,
+            )
             _LOG.info(
                 "session pre-warm: %d executables compiled in %.1fs "
-                "(pod buckets up to %d + dirty-row scatter widths)",
+                "(pod buckets up to %d + dirty-row scatter widths + "
+                "explain readbacks)",
                 n, time.monotonic() - t0, self.prewarm_buckets,
             )
+        if len(assigned) >= self.FREEZE_HEAP_PODS:
+            # Collect first: garbage frozen now would never be freed.
+            gc.collect()
+            gc.freeze()
         return session
 
     @staticmethod
@@ -2122,6 +2178,8 @@ class IncrementalBatchScheduler(BatchScheduler):
                 # snapshots the caches anyway: don't let deltas pile
                 # up unboundedly in a quiet cluster.
                 self._event_q.clear()
+            if self._session is not None:
+                self._run_deferred_explain()
             self._refresh_capacity_idle()
             return 0
         with tracing.trace(

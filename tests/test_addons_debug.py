@@ -114,9 +114,16 @@ class TestDebugEndpoints:
 
     def test_request_log_records(self, server):
         urllib.request.urlopen(server.address + "/api/v1/nodes").read()
-        body = urllib.request.urlopen(
-            server.address + "/debug/requests"
-        ).read().decode()
+        # The handler records a request after its reply is sent: poll
+        # until the record lands.
+        deadline = time.monotonic() + 5.0
+        while True:
+            body = urllib.request.urlopen(
+                server.address + "/debug/requests"
+            ).read().decode()
+            if "/api/v1/nodes" in body or time.monotonic() > deadline:
+                break
+            time.sleep(0.05)
         assert "/api/v1/nodes" in body
         assert "GET" in body
 

@@ -7,6 +7,7 @@ import json
 import time
 import urllib.request
 
+import numpy as np
 import pytest
 
 from kubernetes_tpu.client import Client, HTTPTransport, LocalTransport
@@ -203,6 +204,69 @@ class TestExplainBacklogShape:
             assert set(v["components"]) == {
                 "leastRequested", "balanced", "spreading",
             }
+
+
+def _explain_cluster(kind, seed):
+    from tests.test_pallas_scan import banded_cluster
+    from tests.test_solver_parity import random_cluster
+
+    if kind == "random":
+        return random_cluster(seed)
+    return banded_cluster(seed, n_services=20, n_nodes=12, n_pods=30, multi=0.3)
+
+
+class TestSessionExplain:
+    """SolverSession.explain — the source of the deferred bound-pod
+    tables — against the snapshot path (explain_matrix) on the same
+    cluster, node by node."""
+
+    @staticmethod
+    def _columns(names, bits, comps):
+        cols = {n: j for j, n in enumerate(names)}
+        return cols, bits, comps
+
+    def _assert_same(self, got, want):
+        (gcols, gbits, gcomps), (wcols, wbits, wcomps) = got, want
+        assert set(gcols) == set(wcols)
+        g = [gcols[n] for n in wcols]
+        w = [wcols[n] for n in wcols]
+        assert np.array_equal(gbits[:, g], wbits[:, w])
+        for k in wcomps:
+            assert np.array_equal(gcomps[k][:, g], wcomps[k][:, w]), k
+
+    @pytest.mark.parametrize("release", [False, True])
+    @pytest.mark.parametrize(
+        "kind,seed", [("random", 0), ("random", 5), ("services", 7)]
+    )
+    def test_session_explain_matches_snapshot_path(self, kind, seed, release):
+        """Pending pods against the cluster as it stands; with
+        ``release``, bound pods against the cluster less their own
+        charges (the view before their bind)."""
+        import copy
+
+        from kubernetes_tpu.ops import SolverSession
+        from kubernetes_tpu.ops.pipeline import explain_matrix
+
+        pods, nodes, assigned, services = _explain_cluster(kind, seed)
+        session = SolverSession(nodes, services=services, assigned=assigned)
+        keys = []
+        others = assigned
+        if release:
+            pods = []
+            for a in assigned[::2]:
+                p = copy.deepcopy(a)
+                p.spec.node_name = ""
+                pods.append(p)
+                keys.append(f"default/{a.metadata.name}")
+            others = assigned[1::2]
+        got = session.explain(pods, release=keys)
+        want = explain_matrix(pods, nodes, others, services)
+        self._assert_same(self._columns(*got), self._columns(*want))
+        if release:
+            # The release is a view: the mirror keeps every charge.
+            again = SolverSession(nodes, services=services, assigned=assigned)
+            for k in session.h:
+                assert np.array_equal(session.h[k], again.h[k]), k
 
 
 class TestDecisionsEndToEnd:

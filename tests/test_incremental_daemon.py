@@ -93,6 +93,31 @@ class TestIncrementalDaemon:
         nodes = {bound_node(client, f"p{i}") for i in range(10)}
         assert len(nodes) == 3
 
+    def test_large_session_build_freezes_heap(self, client, monkeypatch):
+        """A session built over FREEZE_HEAP_PODS bound pods or more moves
+        the heap out of the cyclic collector's reach; a smaller one
+        leaves the collector as it was."""
+        import gc
+
+        client.create("nodes", node_wire("n0"))
+        for i in range(3):
+            wire = pod_wire(f"b{i}")
+            wire["spec"]["nodeName"] = "n0"
+            client.create("pods", wire, namespace="default")
+        config = SchedulerConfig(client).start()
+        try:
+            assert config.wait_for_sync()
+            daemon = IncrementalBatchScheduler(config)
+            gc.unfreeze()
+            daemon._build_session()
+            assert gc.get_freeze_count() == 0
+            monkeypatch.setattr(IncrementalBatchScheduler, "FREEZE_HEAP_PODS", 3)
+            daemon._build_session()
+            assert gc.get_freeze_count() > 0
+        finally:
+            gc.unfreeze()
+            config.stop()
+
     def test_delete_frees_occupancy(self, client, sched):
         # One node that fits exactly two pods' CPU.
         client.create("nodes", node_wire("solo", cpu="1"))

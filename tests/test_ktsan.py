@@ -554,6 +554,28 @@ def test_scatter_rows_parity():
         np.testing.assert_array_equal(np.asarray(got[k]), want[k])
 
 
+def test_scatter_rows_banded_parity():
+    import jax.numpy as jnp
+
+    from kubernetes_tpu.ops.oracle import scatter_rows_banded_numpy
+    from kubernetes_tpu.ops.pallas_scan import scatter_rows_banded
+
+    rng = np.random.default_rng(1)
+    counts = rng.integers(0, 110, size=(16, 128)).astype(np.int16)
+    svc = rng.integers(0, 16, size=8).astype(np.int32)
+    slot = rng.integers(0, 128, size=8).astype(np.int32)
+    # The session pads by repeating its last entry: the same write twice.
+    svc[-1], slot[-1] = svc[-2], slot[-2]
+    value = rng.integers(0, 110, size=8).astype(np.int16)
+    value[-1] = value[-2]
+    want = scatter_rows_banded_numpy(counts, svc, slot, value)
+    got = scatter_rows_banded(
+        jnp.asarray(counts), jnp.asarray(svc), jnp.asarray(slot),
+        jnp.asarray(value),
+    )
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
 # -- recompilation sentinel ---------------------------------------------
 
 

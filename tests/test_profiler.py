@@ -211,6 +211,17 @@ class TestLedgerCompleteness:
         sess = SolverSession(nodes)                         # _scatter_rows
         sess.upsert_node(nodes[0])
         sess._flush_dirty()
+        import jax.numpy as jnp
+
+        from kubernetes_tpu.ops.pallas_scan import (
+            count_points,
+            scatter_rows_banded,
+        )
+
+        scatter_rows_banded(                                # banded counts
+            jnp.zeros((8, 128), jnp.int16),
+            *count_points(np.ones((128, 1), np.float32), [(0, 3)], 8),
+        )
         candidate_prefixes_device(                          # preemption kernel
             np.array([100.0]), np.array([64.0]),
             np.array([0], np.int64), np.array([0], np.int32),

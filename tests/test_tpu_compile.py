@@ -15,6 +15,7 @@ only the xdist worker given this file loads the TPU compiler library.
 import os
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
@@ -29,7 +30,10 @@ from kubernetes_tpu.ops.pallas_scan import (
     MAX_NODES,
     VMEM_BUDGET_BYTES,
     _solve_packed,
+    _svc_pad,
     _vmem_bytes,
+    count_points,
+    scatter_rows_banded,
 )
 from kubernetes_tpu.ops.pipeline import DEFAULT_CHUNK
 from kubernetes_tpu.ops.solver import DEFAULT_WEIGHTS, _solve_with_state_xla
@@ -38,6 +42,9 @@ from kubernetes_tpu.ops.solver import DEFAULT_WEIGHTS, _solve_with_state_xla
 #: scatter widths top out here (SolverSession.prewarm).
 SESSION_NODES = 5000
 MAX_SCATTER_WIDTH = 512
+#: Services of the largest configured cluster (kbench svc-5k: 150,000
+#: pods, 15 a Service).
+MAX_SERVICES = 10_000
 
 
 @pytest.fixture(scope="module")
@@ -101,8 +108,24 @@ def session():
     )
 
 
-def _compile_pallas(pods, nodes):
-    compiled = _solve_packed.lower(pods, nodes, DEFAULT_WEIGHTS).compile()
+def _banded(nodes, S=None):
+    """Node shapes with the service counts in the kernel's layout,
+    (S_pad, N) int16, for ``S`` services (default: as given)."""
+    N, S0 = nodes["svc_counts"].shape
+    sharding = getattr(nodes["svc_counts"], "sharding", None)
+    counts = jax.ShapeDtypeStruct(
+        (_svc_pad(S0 if S is None else S), N), jnp.int16, sharding=sharding
+    )
+    return dict(nodes, svc_counts=counts)
+
+
+def _compile_pallas(pods, nodes, S=None):
+    """Compile the kernel for (N, S)-schema node shapes, banded."""
+    banded = _banded(nodes, S)
+    rest = {k: v for k, v in banded.items() if k != "svc_counts"}
+    compiled = _solve_packed.lower(
+        pods, rest, banded["svc_counts"], DEFAULT_WEIGHTS
+    ).compile()
     assert "tpu_custom_call" in compiled.as_text()
     return compiled
 
@@ -122,7 +145,7 @@ def _fits_vmem(nodes) -> bool:
 )
 def test_pallas_scan_whole_backlog(on_chip, backlog, n_pods, n_nodes):
     d, _objs = backlog(n_pods, n_nodes)
-    # pallas_eligible's shape rule admits this shape on one chip.
+    # pallas_reason's shape rule admits this shape on one chip.
     assert d.nodes["cpu_cap"].shape[0] <= MAX_NODES and _fits_vmem(d.nodes)
     _compile_pallas(on_chip(d.pods), on_chip(d.nodes))
 
@@ -154,9 +177,47 @@ def test_session_solve(on_chip, session, bucket):
     _compile_pallas(on_chip(pods), on_chip(session.dev))
 
 
+def test_session_solve_at_documented_limits(on_chip, session):
+    """8,192 node slots (5,000 nodes bucketed) and 10,000 Services: the
+    kernel keeps no Service term in VMEM and takes the shape."""
+    N = session.N_cap
+    assert N == MAX_NODES
+    assert _vmem_bytes(N, MAX_SERVICES, session.LW, session.PW, session.VW) <= (
+        VMEM_BUDGET_BYTES
+    )
+    pods = session._stage_arrays([], 1024, reuse=False)
+    compiled = _compile_pallas(on_chip(pods), on_chip(session.dev), MAX_SERVICES)
+    # The count carry is updated in place: no copy of its 164 MB.
+    mem = compiled.memory_analysis()
+    carry = _svc_pad(MAX_SERVICES) * N * 2
+    assert mem.alias_size_in_bytes >= carry
+    assert mem.temp_size_in_bytes < carry
+
+
 def test_session_scatter_rows(on_chip, session):
     idx = np.zeros(MAX_SCATTER_WIDTH, np.int32)
     rows = {k: v[idx] for k, v in session.h.items()}
     _scatter_rows.lower(
         on_chip(session.dev), on_chip(idx), on_chip(rows)
     ).compile()
+
+
+def test_session_scatter_banded_columns(on_chip, session):
+    """The delete patch of a banded session at 10,000 Services: the
+    rows of the per-node leaves, then point writes of count entries
+    into the (S_pad, N) int16 carry, in place — no copy of its 164 MB."""
+    idx = np.zeros(MAX_SCATTER_WIDTH, np.int32)
+    rows = {k: v[idx] for k, v in session.h.items() if k != "svc_counts"}
+    nodes = on_chip({k: v for k, v in session.dev.items() if k != "svc_counts"})
+    _scatter_rows.lower(nodes, on_chip(idx), on_chip(rows)).compile()
+    counts = _banded(on_chip(session.dev), MAX_SERVICES)["svc_counts"]
+    svc, slot, value = count_points(
+        np.zeros((1, 1), np.float32), [(0, 0)], MAX_SCATTER_WIDTH
+    )
+    compiled = scatter_rows_banded.lower(
+        counts, on_chip(svc), on_chip(slot), on_chip(value)
+    ).compile()
+    mem = compiled.memory_analysis()
+    carry = _svc_pad(MAX_SERVICES) * session.N_cap * 2
+    assert mem.alias_size_in_bytes >= carry
+    assert mem.temp_size_in_bytes < carry
